@@ -15,8 +15,10 @@ build: native
 
 native: $(NATIVE_LIB)
 
+# build beside the target and rename: a process that has the old library
+# mapped keeps its inode
 $(NATIVE_LIB): native/ccsnap.cpp
-	$(CXX) $(CXXFLAGS) -shared -o $@ $<
+	$(CXX) $(CXXFLAGS) -shared -o $@.tmp $< && mv -f $@.tmp $@
 
 # Format/boilerplate gate (reference: make verify-gofmt + golangci-lint +
 # verify-boilerplate.sh, /root/reference/Makefile:41,54-66).  Self-contained:
@@ -70,7 +72,7 @@ test-fuzz:
 # site; each injected OOM/hang/corruption must degrade down the runtime
 # ladder to a bit-identical result (runtime/, tests/test_runtime.py).
 chaos:
-	JAX_PLATFORM_NAME=cpu $(PY) -m pytest tests/test_runtime.py -q
+	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_runtime.py -q
 
 # Multi-host DCN proof: 2 CPU processes over one 8-device mesh.
 test-dist:
@@ -79,17 +81,17 @@ test-dist:
 # Integration smoke: drive the CLI end-to-end against the example snapshot
 # (the analog of test/integration-tests.sh's live-cluster grep).
 test-integration:
-	JAX_PLATFORM_NAME=cpu $(PY) -m cluster_capacity_tpu cluster-capacity \
+	JAX_PLATFORMS=cpu $(PY) -m cluster_capacity_tpu cluster-capacity \
 		--podspec examples/pod.yaml --snapshot examples/cluster-snapshot.yaml \
 		--verbose | grep -q "Termination reason"
-	JAX_PLATFORM_NAME=cpu $(PY) -m cluster_capacity_tpu genpod \
+	JAX_PLATFORMS=cpu $(PY) -m cluster_capacity_tpu genpod \
 		--snapshot examples/cluster-snapshot.yaml --namespace limited \
 		| grep -q "cluster-capacity-stub-container"
 	@echo integration OK
 
 # e2e: multichip dryrun on a virtual 8-device CPU mesh + bench smoke.
 test-e2e:
-	XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORM_NAME=cpu \
+	XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
 		$(PY) -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
 
 bench:
@@ -105,7 +107,7 @@ bench:
 # rung.
 INTERLEAVE_SCALES ?= 2000,16000
 multichip:
-	XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORM_NAME=cpu \
+	XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
 		$(PY) -m tools.multichip_bench --out MULTICHIP_r07.json \
 		--interleave-scales $(INTERLEAVE_SCALES)
 

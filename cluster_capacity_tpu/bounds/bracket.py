@@ -247,7 +247,9 @@ def _bracket_runner(num_constraints: int, num_domains: int, mesh=None):
         up = jnp.floor(frac)
         upper = jnp.sum(up)
         lower = upper
-        lp = jnp.sum(frac)
+        # per-node LP headroom: the host sums it in f64, so the bracket's
+        # frac does not depend on how a mesh splits the node axis
+        lp = frac
         if num_constraints:
             onehot = (dom[:, :, None]
                       == jnp.arange(num_domains, dtype=dom.dtype)[None, None])
@@ -273,6 +275,11 @@ def _bracket_runner(num_constraints: int, num_domains: int, mesh=None):
     vm = jax.vmap(one)
     if mesh is None:
         return jax.jit(vm)
+    in_sh, out_sh = _bracket_shardings(mesh)
+    return jax.jit(vm, in_shardings=in_sh, out_shardings=out_sh)
+
+
+def _bracket_shardings(mesh):
     from jax.sharding import NamedSharding, PartitionSpec as P
     from ..parallel.mesh import BATCH_AXIS, NODE_AXIS
 
@@ -289,8 +296,17 @@ def _bracket_runner(num_constraints: int, num_domains: int, mesh=None):
              s(None),                        # skew [B, C]
              s(None),                        # mindom [B, C]
              s(None))                        # selfm [B, C]
-    out_sh = (s(), s(), s())                 # lower/upper/lp [B]
-    return jax.jit(vm, in_shardings=in_sh, out_shardings=out_sh)
+    out_sh = (s(), s(), s(NODE_AXIS))        # lower/upper [B], lp [B, N]
+    return in_sh, out_sh
+
+
+def _on_mesh(shardings, args):
+    """Host arrays -> global arrays on the mesh.  Every process holds the
+    full arrays and supplies its addressable shards: jit refuses host
+    arrays with non-trivial shardings once the mesh spans processes."""
+    import jax
+    return [jax.make_array_from_callback(a.shape, sh, lambda i, a=a: a[i])
+            for a, sh in zip(args, shardings)]
 
 
 def _spread_arrays(pb: enc.EncodedProblem, ch: int, dh: int, n: int):
@@ -414,8 +430,11 @@ def bracket_device(pbs: Sequence[enc.EncodedProblem], *,
                     "carry": None,
                     "meta": {"n_nodes": n, "n_pad": free.shape[1],
                              "batch": b, "b_pad": free.shape[0]}}
-        lo, hi, lp = runner(free, req, pods_free, gate,
-                            dom, e, valid, skew, mindom, selfm)
+        args = (free, req, pods_free, gate, dom, e, valid, skew, mindom,
+                selfm)
+        if mesh is not None:
+            args = _on_mesh(_bracket_shardings(mesh)[0], args)
+        lo, hi, lp = runner(*args)
         lo, hi, lp = np.asarray(lo), np.asarray(hi), np.asarray(lp)
     elif lower_only:
         return None                      # all-sentinel batch: nothing lowers
@@ -434,7 +453,8 @@ def bracket_device(pbs: Sequence[enc.EncodedProblem], *,
             out.append(CapacityBracket(int(min(lower, UNBOUNDED)),
                                        int(min(upper, UNBOUNDED)),
                                        exact=exact_capacity(pb),
-                                       frac=float(lp[i])))
+                                       frac=float(np.sum(
+                                           lp[i, :n], dtype=np.float64))))
     return out
 
 
@@ -486,16 +506,21 @@ def _auction_runner(rounds: int, mesh=None):
     if mesh is None:
         return jax.jit(run)
     from jax.sharding import NamedSharding, PartitionSpec as P
+    return jax.jit(run, in_shardings=_auction_shardings(mesh),
+                   out_shardings=NamedSharding(mesh, P(None)))
+
+
+def _auction_shardings(mesh):
+    from jax.sharding import NamedSharding, PartitionSpec as P
     from ..parallel.mesh import NODE_AXIS
 
     def s(*parts):
         return NamedSharding(mesh, P(*parts))
 
-    in_sh = (s(NODE_AXIS, None),             # free [N, R]
-             s(NODE_AXIS),                   # pods_free [N]
-             s(None, None),                  # reqs [T, R]
-             s(None, NODE_AXIS))             # gates [T, N]
-    return jax.jit(run, in_shardings=in_sh, out_shardings=s(None))
+    return (s(NODE_AXIS, None),              # free [N, R]
+            s(NODE_AXIS),                    # pods_free [N]
+            s(None, None),                   # reqs [T, R]
+            s(None, NODE_AXIS))              # gates [T, N]
 
 
 def _mix_arrays(pbs: Sequence[enc.EncodedProblem]):
@@ -541,7 +566,10 @@ def auction_device(pbs: Sequence[enc.EncodedProblem],
                 "carry": None,
                 "meta": {"n_nodes": n, "n_pad": free.shape[0],
                          "batch": len(pbs), "b_pad": len(pbs)}}
-    claimed = np.asarray(runner(free, pods_free, reqs, gates))
+    args = (free, pods_free, reqs, gates)
+    if mesh is not None:
+        args = _on_mesh(_auction_shardings(mesh), args)
+    claimed = np.asarray(runner(*args))
     return [int(c) for c in claimed]
 
 

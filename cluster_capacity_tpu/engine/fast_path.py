@@ -89,7 +89,7 @@ def eligible(pb: enc.EncodedProblem) -> bool:
     profile = pb.profile
     # TaintToleration / NodeAffinity normalize over the per-step feasible
     # set — cross-node in general, but a CONSTANT when the raw scores are
-    # uniform over the statically-eligible nodes (VERDICT r3 #6: dedicated
+    # uniform over the statically-eligible nodes (e.g. dedicated
     # pools where every node carries the same PreferNoSchedule taint, or a
     # preferred term matching every node, now ride the fast path).
     if profile.score_weight("TaintToleration") \

@@ -27,27 +27,57 @@ DNS = ("NoSchedule", "NoExecute")
 class OracleState:
     """Mutable cluster state during a sequential simulation."""
 
-    def __init__(self, snapshot: ClusterSnapshot):
+    def __init__(self, snapshot: ClusterSnapshot, memo: bool = False):
         self.snapshot = snapshot
         self.pods_by_node: List[List[dict]] = [list(p)
                                                for p in snapshot.pods_by_node]
+        # With memo, cluster-wide tallies a filter needs for every node
+        # (spread domain counts, affinity counts) are built once per
+        # placement, not once per node, and per-node sums once per change
+        # of that node; place() drops what it invalidates.  Callers that
+        # edit pods_by_node directly (preemption dry runs) leave it off.
+        self.memo: Optional[Dict[tuple, object]] = {} if memo else None
+        self.node_memo: Optional[Dict[tuple, object]] = {} if memo else None
+
+    def place(self, i: int, pod: dict) -> None:
+        self.pods_by_node[i].append(pod)
+        if self.memo is not None:
+            self.memo.clear()
+            for key in [k for k in self.node_memo if k[1] == i]:
+                del self.node_memo[key]
+
+    def cached(self, key: tuple, build, per_node: bool = False):
+        memo = self.node_memo if per_node else self.memo
+        if memo is None:
+            return build()
+        if key not in memo:
+            memo[key] = build()
+        return memo[key]
 
     def requested(self, i: int) -> Dict[str, int]:
-        agg: Dict[str, int] = {}
-        for pod in self.pods_by_node[i]:
-            for k, v in ps.pod_requests(pod).items():
-                agg[k] = agg.get(k, 0) + v
-        return agg
+        def build():
+            agg: Dict[str, int] = {}
+            for pod in self.pods_by_node[i]:
+                for k, v in ps.pod_requests(pod).items():
+                    agg[k] = agg.get(k, 0) + v
+            return agg
+        return dict(self.cached(("req", i), build, per_node=True))
 
     def nonzero_requested(self, i: int) -> Tuple[int, int]:
-        cpu = mem = 0
-        for pod in self.pods_by_node[i]:
-            c, m = ps.pod_nonzero_cpu_mem(pod)
-            cpu += c
-            mem += m
-        return cpu, mem
+        def build():
+            cpu = mem = 0
+            for pod in self.pods_by_node[i]:
+                c, m = ps.pod_nonzero_cpu_mem(pod)
+                cpu += c
+                mem += m
+            return cpu, mem
+        return self.cached(("nz", i), build, per_node=True)
 
     def allocatable(self, i: int) -> Dict[str, int]:
+        return dict(self.cached(("alloc", i), lambda: self._allocatable(i),
+                                per_node=True))
+
+    def _allocatable(self, i: int) -> Dict[str, int]:
         out = {}
         alloc = ((self.snapshot.nodes[i].get("status") or {})
                  .get("allocatable")) or {}
@@ -183,13 +213,16 @@ def _spread_filter(state: OracleState, i: int, pod: dict) -> Optional[str]:
         if key not in node_labels:
             return ("node(s) didn't match pod topology spread constraints "
                     "(missing required label)")
-        counts: Dict[str, int] = {}
-        for j in range(snap.num_nodes):
-            if not _spread_countable(state, j, pod, constraints, c):
-                continue
-            val = snap.node_labels(j).get(key)
-            counts[val] = counts.get(val, 0) + _count_match(
-                state.pods_by_node[j], c.get("labelSelector"), ns)
+        def domain_counts(c=c, key=key) -> Dict[str, int]:
+            counts: Dict[str, int] = {}
+            for j in range(snap.num_nodes):
+                if not _spread_countable(state, j, pod, constraints, c):
+                    continue
+                val = snap.node_labels(j).get(key)
+                counts[val] = counts.get(val, 0) + _count_match(
+                    state.pods_by_node[j], c.get("labelSelector"), ns)
+            return counts
+        counts = state.cached(("spread", id(pod), ci), domain_counts)
         min_domains = int(c.get("minDomains") or 1)
         if not counts:
             min_match = 2**31 - 1
@@ -235,20 +268,41 @@ def _ipa_filter(state: OracleState, i: int, pod: dict) -> Optional[str]:
     aff_terms = _req_terms(pod, "podAffinity")
     anti_terms = _req_terms(pod, "podAntiAffinity")
 
-    # affinityCounts / antiAffinityCounts over all existing pods
-    aff_counts: Dict[Tuple[str, str], int] = {}
-    anti_counts: Dict[Tuple[str, str], int] = {}
-    for j in range(snap.num_nodes):
-        j_labels = snap.node_labels(j)
-        for p in state.pods_by_node[j]:
-            for terms, counts in ((aff_terms, aff_counts),
-                                  (anti_terms, anti_counts)):
-                for t in terms:
+    def term_counts():
+        # affinityCounts / antiAffinityCounts over all existing pods
+        aff_counts: Dict[Tuple[str, str], int] = {}
+        anti_counts: Dict[Tuple[str, str], int] = {}
+        if not (aff_terms or anti_terms):
+            return aff_counts, anti_counts
+        for j in range(snap.num_nodes):
+            j_labels = snap.node_labels(j)
+            for p in state.pods_by_node[j]:
+                for terms, counts in ((aff_terms, aff_counts),
+                                      (anti_terms, anti_counts)):
+                    for t in terms:
+                        key = t.get("topologyKey", "")
+                        if key in j_labels and _term_matches(t, owner_ns, p,
+                                                             ns_labels):
+                            pair = (key, j_labels[key])
+                            counts[pair] = counts.get(pair, 0) + 1
+        return aff_counts, anti_counts
+
+    def existing_anti():
+        # (topologyKey, value) pairs whose existing pods' required
+        # anti-affinity terms match the incoming pod
+        pairs = []
+        for j in range(snap.num_nodes):
+            j_labels = snap.node_labels(j)
+            for p in state.pods_by_node[j]:
+                p_ns = (p.get("metadata") or {}).get("namespace") or "default"
+                for t in _req_terms(p, "podAntiAffinity"):
                     key = t.get("topologyKey", "")
-                    if key in j_labels and _term_matches(t, owner_ns, p,
+                    if key in j_labels and _term_matches(t, p_ns, pod,
                                                          ns_labels):
-                        pair = (key, j_labels[key])
-                        counts[pair] = counts.get(pair, 0) + 1
+                        pairs.append((key, j_labels[key]))
+        return pairs
+
+    aff_counts, anti_counts = state.cached(("ipa", id(pod)), term_counts)
 
     if aff_terms:
         pods_exist = True
@@ -275,18 +329,11 @@ def _ipa_filter(state: OracleState, i: int, pod: dict) -> Optional[str]:
             return "node(s) didn't match pod anti-affinity rules"
 
     # existing pods' required anti-affinity vs incoming
-    for j in range(snap.num_nodes):
-        j_labels = snap.node_labels(j)
-        for p in state.pods_by_node[j]:
-            p_ns = (p.get("metadata") or {}).get("namespace") or "default"
-            for t in _req_terms(p, "podAntiAffinity"):
-                key = t.get("topologyKey", "")
-                if key not in j_labels:
-                    continue
-                if _term_matches(t, p_ns, pod, ns_labels):
-                    if node_labels.get(key) == j_labels[key]:
-                        return ("node(s) didn't satisfy existing pods "
-                                "anti-affinity rules")
+    for key, val in state.cached(("ipa_existing_anti", id(pod)),
+                                 existing_anti):
+        if node_labels.get(key) == val:
+            return ("node(s) didn't satisfy existing pods "
+                    "anti-affinity rules")
     return None
 
 
@@ -736,7 +783,7 @@ def simulate(snapshot: ClusterSnapshot, template: dict,
     from ..ops import volumes as vol_ops
 
     profile = profile or SchedulerProfile.parity()
-    state = OracleState(snapshot)
+    state = OracleState(snapshot, memo=True)
     placements: List[int] = []
     step = 0
     n = snapshot.num_nodes
@@ -815,5 +862,5 @@ def simulate(snapshot: ClusterSnapshot, template: dict,
         placed_per_node[best] += 1
         clone = ps.make_clone(template, step)
         clone["spec"]["nodeName"] = snapshot.node_names[best]
-        state.pods_by_node[best].append(clone)
+        state.place(best, clone)
         step += 1

@@ -107,6 +107,17 @@ class PreemptionOutcome:
         return self.node_index is not None
 
 
+def may_find_victims(snapshot: ClusterSnapshot, pod: Mapping) -> bool:
+    """False when evaluate() cannot succeed: the pod may not preempt
+    (preemptionPolicy Never) or no pod in the snapshot has a lower
+    priority, so no node can yield victims."""
+    if ((pod.get("spec") or {}).get("preemptionPolicy")) == "Never":
+        return False
+    incoming = resolve_priority(pod, snapshot.priority_classes)
+    return any(resolve_priority(p, snapshot.priority_classes) < incoming
+               for plist in snapshot.pods_by_node for p in plist)
+
+
 def _is_unresolvable(reason: Optional[str]) -> bool:
     if reason is None:
         return False

@@ -38,6 +38,8 @@ Counters:
 Gauges:
     cc_sweep_templates                    templates in the current sweep
     cc_sweep_groups{mode}                 batched/fast_path/sequential groups
+    cc_sharded_carry_devices              distinct devices holding shards of
+        the last mesh-sharded group solve's final carry (parallel/sweep.py)
     cc_resilience_scenarios{state}        total/completed scenario progress
     cc_explain_reason_nodes{reason}       nodes per terminal why-not reason
         in the most recent explained solve
@@ -63,6 +65,7 @@ RECOMPILES = "cc_recompiles_total"
 COMPILE_SECONDS = "cc_compile_seconds_total"
 SPANS_DROPPED = "cc_trace_spans_dropped_total"
 SWEEP_TEMPLATES = "cc_sweep_templates"
+SHARDED_CARRY_DEVICES = "cc_sharded_carry_devices"
 SWEEP_GROUPS = "cc_sweep_groups"
 SCENARIOS = "cc_resilience_scenarios"
 EXPLAINS = "cc_explains_total"

@@ -506,8 +506,8 @@ def compute_slot_columns(snapshot, reqs: List[SlotRequest],
         # settle; a shared allocation's units are searched JOINTLY with
         # the clones so a greedy shared reservation cannot strand the
         # pool), and exact feasibility is monotone in k, so binary search
-        # finds the true maximum (r5: replaces the r4 greedy lower bound,
-        # VERDICT r4 #3).  A budget-exhausted probe (None) breaks
+        # finds the true maximum (r5: replaces the r4 greedy lower
+        # bound).  A budget-exhausted probe (None) breaks
         # monotonicity — fall back to False there and rescue with the r4
         # exponential step-down probes afterwards.
         unknown = False

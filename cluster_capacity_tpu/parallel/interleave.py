@@ -14,7 +14,7 @@ so each queue pop is pure elementwise/reduction work on device.
 Scope (everything else falls back to the object path, which stays the
 differential oracle for this engine — tests/test_interleave_tensor.py):
 
-- deterministic profiles; extenders ARE supported (r5, VERDICT r4 #4):
+- deterministic profiles; extenders ARE supported (r5):
   their Filter/Prioritize verdicts are treated as per-(template, node)
   deterministic — called ONCE per template over the full node axis, the
   mask/bonus ride the device step (the object path sends the same template
@@ -301,8 +301,8 @@ def eligible_profile(snapshot: ClusterSnapshot, templates: Sequence[dict],
                      profile: SchedulerProfile) -> Optional[str]:
     """Profile gates checkable BEFORE the O(T*N) encode pass.  Priority
     tiers and preemption are handled natively (tier-ranked pops on device;
-    victim selection as a rare host event between chunks, VERDICT r3 #5);
-    extenders run as one static host round per template (VERDICT r4 #4)."""
+    victim selection as a rare host event between chunks);
+    extenders run as one static host round per template."""
     if not profile.deterministic:
         return "non-deterministic tie-break"
     if profile.extenders and not profile.tensor_extenders:
@@ -763,7 +763,7 @@ def solve_interleaved_tensor(snapshot: ClusterSnapshot,
     preempt_capable = bool(preempt_on and maybe.any())
     preempt_budget = 10 * t_n + 100       # eviction valve (sweep_interleaved)
 
-    # One static extender round per template (VERDICT r4 #4): Filter over
+    # One static extender round per template: Filter over
     # the full node axis -> bool mask, Prioritize -> bonus vector.  Node
     # objects never change during a study (evictions only touch pods), so
     # the verdicts survive rebuilds.  The object path filters the sampled

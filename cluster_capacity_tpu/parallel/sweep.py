@@ -532,10 +532,14 @@ def _batched_solve(pbs: List[enc.EncodedProblem], max_limit: int,
                     bstate = bfused.pack(carry)
                 bstate, chosen, all_stopped = bfused.run_packed(bstate, chunk)
             except Exception as e:
-                # Lazy Mosaic compile/runtime failure: recover the last
+                # Lazy Mosaic compile/runtime failure: raises on the chip
+                # (_mark_failed); in interpret mode recover the last
                 # completed chunk's carry and resume on the XLA path.
-                fused_batched._mark_failed(bfused,
-                                           f"{type(e).__name__}: {e}")
+                from ..runtime.errors import RuntimeFault
+                if isinstance(e, RuntimeFault):
+                    raise
+                fused_batched._mark_failed(
+                    bfused, f"{type(e).__name__}: {e}", e)
                 if bstate is not None:
                     carry = bfused.unpack(bstate, carry)
                 bfused = None
@@ -556,6 +560,11 @@ def _batched_solve(pbs: List[enc.EncodedProblem], max_limit: int,
 
     explain = explain and mesh is None   # attribution is a per-template
     if mesh is not None:
+        from ..obs import names as obs_names
+        from ..utils.metrics import default_registry
+        default_registry.set_gauge(
+            obs_names.SHARDED_CARRY_DEVICES,
+            len({sh.device for sh in carry.placed.addressable_shards}))
         # slice the node-axis pads back off before any host-side consumer
         # (diagnose reads the carry against the UNPADDED host consts)
         carry = mesh_lib.unpad_carry(carry, n_nodes)

@@ -63,6 +63,16 @@ class NumericCorruption(RuntimeFault):
     code = "NumericCorruption"
 
 
+class KernelFault(RuntimeFault):
+    """A Pallas kernel compiled for the chip failed to compile, failed at
+    run time, or disagreed with the XLA scan it is cross-checked against.
+    The kernel never hands its work to the XLA scan in silence: the fault
+    crosses the guard like any other, so the ladder records it and
+    `--strict` fails on it."""
+
+    code = "KernelFault"
+
+
 class SnapshotValidationError(RuntimeFault):
     """Malformed or partial snapshot input.  `field_path` names the exact
     offending field (e.g. ``nodes[3].status.allocatable.cpu``) instead of

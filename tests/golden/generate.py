@@ -445,7 +445,7 @@ def main():
 
 
 def _wffc_ipa_scenarios():
-    """Round-5 corpus growth (VERDICT r4 #6): VolumeBinding WFFC +
+    """Round-5 corpus growth: VolumeBinding WFFC +
     CSIStorageCapacity edges (volume_binding.go:417-569, binder.go
     checkVolumeProvisions/hasEnoughCapacity) and InterPodAffinity
     namespaceSelector asymmetries (scoring.go:128-293)."""

@@ -114,7 +114,6 @@ def test_two_process_sharded_solve(tmp_path):
                 "CC_COORDINATOR": f"127.0.0.1:{port}",
                 "CC_NUM_PROCESSES": "2",
                 "CC_PROCESS_ID": str(pid),
-                "JAX_PLATFORM_NAME": "cpu",
                 "JAX_PLATFORMS": "cpu",
                 "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
                 "PYTHONPATH": os.pathsep.join(
@@ -184,7 +183,6 @@ def test_two_process_interleave_smoke(tmp_path):
                 "CC_COORDINATOR": f"127.0.0.1:{port}",
                 "CC_NUM_PROCESSES": "2",
                 "CC_PROCESS_ID": str(pid),
-                "JAX_PLATFORM_NAME": "cpu",
                 "JAX_PLATFORMS": "cpu",
                 "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
                 "PYTHONPATH": os.pathsep.join(

@@ -486,7 +486,7 @@ def _pod_with_shared_claim(name, claim="shared"):
 
 def test_shared_claim_with_cel_selector_structured():
     """A shared named claim WITH a CEL selector must run the structured
-    allocator (VERDICT r2: it used to degrade to count-based matching):
+    allocator (it used to degrade to count-based matching):
     only the node whose devices match the selector can host the one
     allocation; all clones colocate there."""
     nodes = [build_test_node("n1", 100000, int(1e11), 500),
@@ -555,7 +555,7 @@ def test_shared_structured_claim_plus_template_claim():
 # --- sharedCounters exactness (r5: backtracking replaces the greedy bound) -
 
 def test_partitionable_greedy_stranding_exact():
-    """The canonical greedy-failure family (VERDICT r4 #3): first-fit hands
+    """The canonical greedy-failure family: first-fit hands
     the counter-hungry partition to the first clone and strands the pool.
     Pool 20Gi; partitions big{20Gi}, small1{10Gi}, small2{10Gi}: greedy
     takes `big` (device order) and answers 1 clone — the exact backtracking

@@ -82,7 +82,7 @@ def test_fast_ineligible_with_spread():
     assert not fast_path.eligible(pb)
 
 
-# --- widened eligibility: uniform static-score classes (VERDICT r3 #6) ----
+# --- widened eligibility: uniform static-score classes ----
 
 @pytest.mark.parametrize("seed", range(8))
 def test_fast_uniform_taint_class(seed):

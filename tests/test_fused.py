@@ -200,6 +200,21 @@ def test_runtime_mismatch_disables(monkeypatch):
     fused._failed_metas.clear()
 
 
+@pytest.mark.parametrize("batched", [False, True])
+def test_chip_kernel_failure_raises(batched):
+    """On the chip (interpret=False) a kernel failure is a KernelFault that
+    crosses the guard, never a silent switch to the XLA scan."""
+    from types import SimpleNamespace
+    from cluster_capacity_tpu.engine import fused_batched
+    from cluster_capacity_tpu.runtime.errors import KernelFault
+    runner = SimpleNamespace(pk=SimpleNamespace(meta=SimpleNamespace(n=5000)),
+                             interpret=False, b=8, key=("k",))
+    mark = fused_batched._mark_failed if batched else fused.mark_failed
+    with pytest.raises(KernelFault, match="n=5000: divergence"):
+        mark(runner, "divergence", ValueError("x"))
+    assert not fused._failed_metas and not fused_batched._failed_keys
+
+
 def _fuzz_pod_f32(rng):
     """Kernel-eligible mixed-family pod: fit + taints + hard AND soft
     spread + IPA."""
@@ -416,7 +431,7 @@ def test_pack_unpack_roundtrip():
 
 
 # ---------------------------------------------------------------------------
-# Mid-solve verification checkpoints (VERDICT r2 weak #2)
+# Mid-solve verification checkpoints
 # ---------------------------------------------------------------------------
 
 def _ckpt_problem():

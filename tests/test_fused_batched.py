@@ -185,7 +185,7 @@ def test_divergence_disables_group(monkeypatch):
 
 def test_vmem_budget_refuses_oversized():
     """eligible() must refuse plane stacks over the VMEM budget instead of
-    letting Mosaic fail at runtime (VERDICT r2 weak #3)."""
+    letting Mosaic fail at runtime."""
     from cluster_capacity_tpu.engine import fused
 
     pk = fused._Packing(

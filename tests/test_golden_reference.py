@@ -1,6 +1,6 @@
 """Golden outcomes that do NOT flow through this repo's oracle.
 
-The differential suite's oracle is same-author (VERDICT r1 weak item #2);
+The differential suite's oracle is same-author;
 these fixtures pin outcomes whose expected values come from somewhere else:
 the reference repository's own documented/asserted results, or step-by-step
 manual arithmetic on reduced profiles (see tests/golden/README.md).

@@ -19,11 +19,11 @@ SCENARIOS = sorted(glob.glob(os.path.join(GOLDEN_DIR, "*.json")))
 
 
 def test_scenarios_exist():
-    """The mechanism is only real if fixtures ride it (VERDICT r2 #3);
+    """The mechanism is only real if fixtures ride it;
     round 4 grew the corpus to 19 (preemption pickOneNode criteria, RTC
-    shapes, minDomains edges, IPA symmetric weights — VERDICT r3 #4);
+    shapes, minDomains edges, IPA symmetric weights);
     round 5 to 24 (WFFC + CSIStorageCapacity edges, IPA namespaceSelector
-    asymmetries — VERDICT r4 #6)."""
+    asymmetries)."""
     assert len(SCENARIOS) >= 24
 
 

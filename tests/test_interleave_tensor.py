@@ -251,9 +251,9 @@ def test_fallback_reasons():
     prof = SchedulerProfile.parity()
 
     # priorities differing no longer falls back (tier-ranked pops are
-    # native, VERDICT r3 #5) — covered differentially below
+    # native) — covered differentially below
 
-    # extenders no longer fall back (r5, VERDICT r4 #4): one static host
+    # extenders no longer fall back (r5): one static host
     # round per template — covered differentially below
 
     # host ports / inline disks / RWOP run natively as of r5 — covered
@@ -303,7 +303,7 @@ def test_curability_transition_matches_object_path():
         _assert_same(ref, got, f"transition mt={mt}")
 
 
-# --- priority tiers + preemption (VERDICT r3 #5) --------------------------
+# --- priority tiers + preemption --------------------------
 
 def _victim_pod(name, node, cpu_m, priority, labels=None):
     return {"metadata": {"name": name, "namespace": "default",
@@ -383,7 +383,7 @@ def test_preemption_pdb_protected_victims():
 @pytest.mark.parametrize("seed", range(4))
 def test_fuzz_tiered_preemption_corpus(seed):
     """Randomized priority-tiered corpora with existing lower-priority
-    pods (the VERDICT r3 #5 'done' criterion): spread + affinity templates
+    pods: spread + affinity templates
     over three tiers, victims present."""
     rng = np.random.RandomState(400 + seed)
     nodes = _nodes(int(rng.choice([5, 8])), zones=3,
@@ -416,7 +416,7 @@ def test_fuzz_tiered_preemption_corpus(seed):
 
 
 # --------------------------------------------------------------------------
-# extender host-callback rounds (r5, VERDICT r4 #4)
+# extender host-callback rounds (r5)
 # --------------------------------------------------------------------------
 
 def _http_extender_server(filter_fn=None, prioritize_fn=None,

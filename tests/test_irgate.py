@@ -36,7 +36,7 @@ def _run_gate(*extra, timeout=600):
     env = dict(os.environ)
     for k in ("CC_TPU_FUSED", "CC_INJECT_FAULT", "JAX_ENABLE_X64"):
         env.pop(k, None)
-    env["JAX_PLATFORM_NAME"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     return subprocess.run(
         [sys.executable, "-m", "tools.irgate", *extra],
         cwd=REPO, env=env, capture_output=True, text=True, timeout=timeout)

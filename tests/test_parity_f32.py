@@ -1,4 +1,4 @@
-"""Measured f32==f64 parity demonstration (VERDICT r2 missing #4).
+"""Measured f32==f64 parity demonstration.
 
 TPU hardware has no native float64, so a fused-kernel f64 mode cannot be a
 TPU fast path.  The parity story is instead a measured chain:

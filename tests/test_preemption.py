@@ -2,7 +2,9 @@
 reference semantics from vendor/.../framework/preemption/preemption.go)."""
 
 from cluster_capacity_tpu import ClusterCapacity, SchedulerProfile
-from cluster_capacity_tpu.engine.preemption import resolve_priority
+from cluster_capacity_tpu.engine.preemption import (may_find_victims,
+                                                    resolve_priority)
+from cluster_capacity_tpu.models.snapshot import ClusterSnapshot
 from cluster_capacity_tpu.models.podspec import default_pod
 
 from helpers import build_test_node, build_test_pod
@@ -43,6 +45,20 @@ def test_no_preemption_among_equal_priority():
     res = _run(incoming, nodes, pods=[squatter])
     assert res.placed_count == 0
     assert res.fail_counts.get("Insufficient cpu") == 1
+
+
+def test_may_find_victims():
+    """The framework skips the per-node preemption pass exactly when no
+    node could yield victims."""
+    nodes = [build_test_node("n1", 1000, int(1e9), 10)]
+    squatter = build_test_pod("squatter", 800, 0, node_name="n1")
+    snap = ClusterSnapshot.from_objects(nodes, [squatter])
+    incoming = build_test_pod("vip", 600, 0)
+    assert not may_find_victims(snap, incoming)          # equal priority
+    incoming["spec"]["priority"] = 100
+    assert may_find_victims(snap, incoming)
+    incoming["spec"]["preemptionPolicy"] = "Never"
+    assert not may_find_victims(snap, incoming)
 
 
 def test_preemption_prefers_fewest_victims():

@@ -44,7 +44,7 @@ def test_sharded_sweep_matches_unsharded():
 @needs_8
 def test_sharded_topology_state_matches_unsharded():
     """Carried spread/IPA per-node counts sharded over the node axis must
-    reproduce the unsharded placements exactly (VERDICT r1 weak item #4)."""
+    reproduce the unsharded placements exactly."""
     from cluster_capacity_tpu import SchedulerProfile
     from cluster_capacity_tpu.models.snapshot import ClusterSnapshot
     from cluster_capacity_tpu.models.podspec import default_pod

@@ -201,7 +201,7 @@ def test_interleaved_scheduling_gates_and_sampling():
 
 
 # ---------------------------------------------------------------------------
-# Interleaved-mode feature parity with single-template runs (VERDICT r2 #7):
+# Interleaved-mode feature parity with single-template runs:
 # preemption, eviction-triggered requeue, and extender Filter/Prioritize/Bind.
 # ---------------------------------------------------------------------------
 

@@ -34,7 +34,6 @@ ROOT = os.path.dirname(os.path.dirname(_HERE))
 
 # irgate is CPU-only by contract: lowering needs no accelerator, and the
 # committed budgets assume the CPU lowering path with x64 disabled.
-os.environ.setdefault("JAX_PLATFORM_NAME", "cpu")
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 

@@ -23,7 +23,7 @@ import sys
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent.parent
-TARGETS = ["cluster_capacity_tpu", "tests", "bench.py", "tpu_capture.py",
+TARGETS = ["cluster_capacity_tpu", "tests", "bench.py",
            "__graft_entry__.py", "tools"]
 SKIP_PARTS = {"__pycache__", ".git", "build", "dist"}
 

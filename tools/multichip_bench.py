@@ -27,7 +27,7 @@ interleave_sharded_placements_per_sec (total + per device) pinned from the
 primary interleave scale.
 
 Usage:
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORM_NAME=cpu \
+  XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
       python -m tools.multichip_bench --nodes 2000 --out MULTICHIP_r06.json
 
 The output document keeps MULTICHIP_r05.json's envelope (n_devices / rc /

@@ -38,13 +38,13 @@ ROOT = os.path.dirname(os.path.dirname(_HERE))
 
 # The mesh matrix needs 8 devices; the CPU backend fakes them.  All of
 # this must land before anything imports jax (this jax build reads
-# XLA_FLAGS and JAX_PLATFORM_NAME at import).  CC_TPU_FUSED=0 keeps the
+# XLA_FLAGS and JAX_PLATFORMS at import).  CC_TPU_FUSED=0 keeps the
 # Pallas fused path out of the lowering we budget.
 _FLAG = "--xla_force_host_platform_device_count=8"
 if _FLAG not in os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "") + " " + _FLAG).strip()
-os.environ.setdefault("JAX_PLATFORM_NAME", "cpu")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ["CC_TPU_FUSED"] = "0"
 
 
