@@ -350,7 +350,7 @@ def run(argv: Optional[List[str]] = None, prog: str = "cluster-capacity") -> int
         from ..utils.trace import SPAN_SOLVE, default_tracer
         snapshot, _raw, _opts = current_snapshot()
         t0 = time.perf_counter()
-        with default_tracer.span(SPAN_SOLVE), default_tracer.profile():
+        with default_tracer.span(SPAN_SOLVE):
             if args.interleave:
                 # interleaved shared-state queues don't carry attribution —
                 # the race through one mutable cluster state has no

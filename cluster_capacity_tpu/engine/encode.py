@@ -13,6 +13,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from .. import obs
 from ..models import podspec as ps
 from ..models.podspec import is_scalar_resource_name
 from ..models.snapshot import (ClusterSnapshot, IDX_CPU, IDX_EPHEMERAL, IDX_MEM,
@@ -162,6 +163,14 @@ def encode_problem(snapshot: ClusterSnapshot, pod: dict,
     static scores through those planes, the mask rides the XLA scan and the
     fused Pallas kernel with no solver changes (fused.py packs static_mask as
     the first [S, 128] const plane)."""
+    with obs.span("cc.encode"):
+        return _encode_problem(snapshot, pod, profile, ipa_extra_keys,
+                               alive_mask)
+
+
+def _encode_problem(snapshot: ClusterSnapshot, pod: dict,
+                    profile: SchedulerProfile, ipa_extra_keys,
+                    alive_mask) -> EncodedProblem:
     n = snapshot.num_nodes
     alive = None
     if alive_mask is not None:
@@ -364,37 +373,41 @@ def encode_problem(snapshot: ClusterSnapshot, pod: dict,
         il_score = np.where(alive, il_score, 0.0)
 
     # --- stateful plugins ---------------------------------------------------
-    if enabled("PodTopologySpread"):
-        spread_hard = pod_topology_spread.encode_constraints(
-            snapshot, pod, "DoNotSchedule")
-    else:
-        spread_hard = pod_topology_spread.encode_constraints(
-            snapshot, {"metadata": pod.get("metadata", {}), "spec": {}},
-            "DoNotSchedule")
-    if profile.score_weight("PodTopologySpread"):
-        if (pod.get("spec") or {}).get("topologySpreadConstraints"):
-            spread_soft = pod_topology_spread.encode_constraints(
-                snapshot, pod, "ScheduleAnyway")
+    with obs.span("cc.encode.spread"):
+        if enabled("PodTopologySpread"):
+            spread_hard = pod_topology_spread.encode_constraints(
+                snapshot, pod, "DoNotSchedule")
         else:
-            # system default spreading via service/RC/RS/SS selectors
-            spread_soft = pod_topology_spread.encode_system_default(
-                snapshot, pod)
-    else:
-        spread_soft = pod_topology_spread.encode_constraints(
-            snapshot, {"metadata": pod.get("metadata", {}), "spec": {}},
-            "ScheduleAnyway")
-    require_all = bool((pod.get("spec") or {}).get("topologySpreadConstraints"))
-    spread_ignored = pod_topology_spread.static_ignored(spread_soft, require_all)
-
-    if enabled("InterPodAffinity") or profile.score_weight("InterPodAffinity"):
-        ipa = inter_pod_affinity.encode(
-            snapshot, pod,
-            ignore_preferred_terms_of_existing_pods=
-            profile.ignore_preferred_terms_of_existing_pods,
-            extra_topology_keys=ipa_extra_keys)
-    else:
-        ipa = inter_pod_affinity.encode(
-            snapshot, {"metadata": pod.get("metadata", {}), "spec": {}})
+            spread_hard = pod_topology_spread.encode_constraints(
+                snapshot, {"metadata": pod.get("metadata", {}), "spec": {}},
+                "DoNotSchedule")
+        if profile.score_weight("PodTopologySpread"):
+            if (pod.get("spec") or {}).get("topologySpreadConstraints"):
+                spread_soft = pod_topology_spread.encode_constraints(
+                    snapshot, pod, "ScheduleAnyway")
+            else:
+                # system default spreading via service/RC/RS/SS selectors
+                spread_soft = pod_topology_spread.encode_system_default(
+                    snapshot, pod)
+        else:
+            spread_soft = pod_topology_spread.encode_constraints(
+                snapshot, {"metadata": pod.get("metadata", {}), "spec": {}},
+                "ScheduleAnyway")
+        require_all = bool(
+            (pod.get("spec") or {}).get("topologySpreadConstraints"))
+        spread_ignored = pod_topology_spread.static_ignored(spread_soft,
+                                                            require_all)
+    with obs.span("cc.encode.affinity"):
+        if enabled("InterPodAffinity") \
+                or profile.score_weight("InterPodAffinity"):
+            ipa = inter_pod_affinity.encode(
+                snapshot, pod,
+                ignore_preferred_terms_of_existing_pods=
+                profile.ignore_preferred_terms_of_existing_pods,
+                extra_topology_keys=ipa_extra_keys)
+        else:
+            ipa = inter_pod_affinity.encode(
+                snapshot, {"metadata": pod.get("metadata", {}), "spec": {}})
 
     # --- scan-length upper bound from the fit filter ------------------------
     free = allocatable - init_requested

@@ -38,6 +38,7 @@ from typing import Optional
 
 import numpy as np
 
+from .. import obs
 from . import encode as enc
 from . import simulator as sim
 from ..models.snapshot import IDX_CPU, IDX_PODS
@@ -377,33 +378,39 @@ def solve_fast(pb: enc.EncodedProblem, max_limit: int = 0,
     n = pb.snapshot.num_nodes
     if n == 0:
         return None
-    st = _fast_state(pb)
-    total_cap = st["total_cap"]
-    if total_cap == 0:
-        # nothing places: reuse the scan path for exact diagnosis
-        return None
-    # Mirror the scan's budget exactly, including its unlimited-run cap
-    # (simulator.py solve(): min(hint+1, _DEFAULT_UNLIMITED_CAP)).
-    budget = total_cap if not max_limit else min(max_limit, total_cap)
-    budget = min(budget, sim._DEFAULT_UNLIMITED_CAP)
-    # A node can never take more clones than the whole budget → clip before
-    # sizing the score matrix (bounds memory for small-limit queries); the
-    # _K_FLOOR + power-of-two rounding keep the clip off the jit cache key.
-    caps = np.minimum(st["caps_full"], max(budget, _K_FLOOR))
-    k_max = int(caps.max())
-    K = 1 << max(0, k_max - 1).bit_length()
+    with obs.span("cc.setup"):
+        st = _fast_state(pb)
+        total_cap = st["total_cap"]
+        if total_cap == 0:
+            # nothing places: reuse the scan path for exact diagnosis
+            return None
+        # Mirror the scan's budget exactly, including its unlimited-run cap
+        # (simulator.py solve(): min(hint+1, _DEFAULT_UNLIMITED_CAP)).
+        budget = total_cap if not max_limit else min(max_limit, total_cap)
+        budget = min(budget, sim._DEFAULT_UNLIMITED_CAP)
+        # A node can never take more clones than the whole budget → clip
+        # before sizing the score matrix (bounds memory for small-limit
+        # queries); the _K_FLOOR + power-of-two rounding keep the clip off
+        # the jit cache key.
+        caps = np.minimum(st["caps_full"], max(budget, _K_FLOOR))
+        k_max = int(caps.max())
+        K = 1 << max(0, k_max - 1).bit_length()
     dt = st["dt"]
 
-    run = _fast_solve_device(
-        st["cfg"].fit_strategy_type, st["cfg"].fit_shape, K, n,
-        st["w_fit"], st["w_bal"], st["add_t"], st["add_na"], st["w_il"],
-        st["dt_name"])
-    mono, flat, comp_fit, comp_bal = run(
-        st["alloc_f"], st["base_f"], st["inc_f"], st["freq"], st["fit_w"],
-        st["alloc_b"], st["base_b"], st["inc_b"], st["breq"],
-        st["t_c"], st["na_c"], st["il"], caps.astype(np.int32))
-    if not bool(mono):
-        return None
+    with obs.span("cc.issue"):
+        run = _fast_solve_device(
+            st["cfg"].fit_strategy_type, st["cfg"].fit_shape, K, n,
+            st["w_fit"], st["w_bal"], st["add_t"], st["add_na"], st["w_il"],
+            st["dt_name"])
+        mono, flat, comp_fit, comp_bal = run(
+            st["alloc_f"], st["base_f"], st["inc_f"], st["freq"],
+            st["fit_w"], st["alloc_b"], st["base_b"], st["inc_b"],
+            st["breq"], st["t_c"], st["na_c"], st["il"],
+            caps.astype(np.int32))
+    with obs.span("cc.wait"):
+        if not bool(mono):
+            return None
+        flat_np = np.asarray(flat)
 
     # Sort all valid pairs by (score desc, node asc, k asc).  The flat index
     # is node-major, so a STABLE sort on -score alone yields exactly that
@@ -412,10 +419,10 @@ def solve_fast(pb: enc.EncodedProblem, max_limit: int = 0,
     # after negation: last), and any two stable sorts over identical keys
     # produce the identical permutation, so the selection matches the old
     # on-device argsort bit-for-bit.
-    flat_np = np.asarray(flat)
-    order = np.argsort(-flat_np, kind="stable")
-    chosen_nodes = order[:budget] // K
-    placements = chosen_nodes.astype(np.int64).tolist()
+    with obs.span("cc.fast.sort"):
+        order = np.argsort(-flat_np, kind="stable")
+        chosen_nodes = order[:budget] // K
+        placements = chosen_nodes.astype(np.int64).tolist()
     placed = len(placements)
 
     # Reconstruct the final carry once: the exhausted branch diagnoses from

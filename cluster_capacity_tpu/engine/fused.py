@@ -40,6 +40,7 @@ from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
+from .. import obs
 from ..models.snapshot import IDX_CPU, IDX_PODS
 from . import simulator as sim
 
@@ -998,14 +999,17 @@ class FusedRunner:
         after a stop are no-ops inside the kernel, so speculative chunks
         past the stop point cost only device time, never correctness.
         Returns (new_state, window); pass the window to collect()."""
-        if self.const_stack is None:
-            self.const_stack = _device_const_packer(self.pk)(self._consts)
-        call = _compiled_call(self.pk, k_steps, self.interpret)
-        planes, scalars = state
-        chunks = []
-        for _ in range(depth):
-            planes, scalars, chosen = call(self.const_stack, planes, scalars)
-            chunks.append(chosen)
+        with obs.span("cc.issue", steps=k_steps * depth, lanes=1):
+            if self.const_stack is None:
+                self.const_stack = _device_const_packer(self.pk)(
+                    self._consts)
+            call = _compiled_call(self.pk, k_steps, self.interpret)
+            planes, scalars = state
+            chunks = []
+            for _ in range(depth):
+                planes, scalars, chosen = call(self.const_stack, planes,
+                                               scalars)
+                chunks.append(chosen)
         STATS["chunks"] += depth
         return (planes, scalars), (scalars, chunks)
 
@@ -1015,10 +1019,11 @@ class FusedRunner:
         starts before any blocks (a serial np.asarray per chunk would pay
         the round trip depth+1 times)."""
         scalars, chunks = window
-        for c in chunks:
-            c.copy_to_host_async()
-        sc = np.asarray(scalars)
-        chosen = np.concatenate([np.asarray(c)[:, 0] for c in chunks])
+        with obs.span("cc.wait"):
+            for c in chunks:
+                c.copy_to_host_async()
+            sc = np.asarray(scalars)
+            chosen = np.concatenate([np.asarray(c)[:, 0] for c in chunks])
         return chosen, bool(round(sc[0, 1]))
 
     def run_window(self, state, k_steps: int, depth: int):
@@ -1048,18 +1053,19 @@ def make_runner(cfg: sim.StaticConfig, pb, consts,
     if key in _failed_metas:
         return None
     if verify_against is not None and key not in _verified_metas:
-        v_consts, v_carry, steps = verify_against
-        try:
-            _f_carry, f_chosen = runner.run_chunk(v_carry, steps)
-        except RuntimeFault:
-            raise
-        except Exception as e:
-            mark_failed(runner, f"{type(e).__name__}: {e}", e)
-            return None
-        run_chunk = sim._chunk_runner()
-        _x_carry, x_chosen = run_chunk(cfg, v_consts, v_carry, steps)
-        if not np.array_equal(f_chosen, np.asarray(x_chosen)):
-            mark_failed(runner, "cross-check divergence vs XLA step")
-            return None
+        with obs.span("cc.verify"):
+            v_consts, v_carry, steps = verify_against
+            try:
+                _f_carry, f_chosen = runner.run_chunk(v_carry, steps)
+            except RuntimeFault:
+                raise
+            except Exception as e:
+                mark_failed(runner, f"{type(e).__name__}: {e}", e)
+                return None
+            run_chunk = sim._chunk_runner()
+            _x_carry, x_chosen = run_chunk(cfg, v_consts, v_carry, steps)
+            if not np.array_equal(f_chosen, np.asarray(x_chosen)):
+                mark_failed(runner, "cross-check divergence vs XLA step")
+                return None
         _verified_metas.add(key)
     return runner

@@ -29,6 +29,7 @@ from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
+from .. import obs
 from ..models.snapshot import IDX_CPU, IDX_PODS
 from ..ops.node_resources_fit import _floor_div
 from . import fused
@@ -733,20 +734,22 @@ class BatchedFusedRunner:
         """One fused chunk for the whole group.  Returns (new_state,
         chosen[k_steps, B], all_stopped)."""
         import jax.numpy as jnp
-        if self.const_stack is None:
-            self.const_stack = _device_batched_const_packer(
-                self.pk, self.b)(tuple(self._consts_list))
-            self.scalar_rows_dev = jnp.asarray(self.scalar_rows)
-        call = _compiled_batched_call(self.pk, self.tab, self.b, k_steps,
-                                      self.max_dnh, self.interpret)
-        yout, sout, chosen = call(self.const_stack, state[0], state[1],
-                                  self.scalar_rows_dev)
-        for a in (sout, chosen):             # one round trip, not two
-            if hasattr(a, "copy_to_host_async"):
-                a.copy_to_host_async()
-        sc = np.asarray(sout)[:self.b]
+        with obs.span("cc.issue", steps=k_steps, lanes=self.b):
+            if self.const_stack is None:
+                self.const_stack = _device_batched_const_packer(
+                    self.pk, self.b)(tuple(self._consts_list))
+                self.scalar_rows_dev = jnp.asarray(self.scalar_rows)
+            call = _compiled_batched_call(self.pk, self.tab, self.b, k_steps,
+                                          self.max_dnh, self.interpret)
+            yout, sout, chosen = call(self.const_stack, state[0], state[1],
+                                      self.scalar_rows_dev)
+        with obs.span("cc.wait"):
+            for a in (sout, chosen):             # one round trip, not two
+                if hasattr(a, "copy_to_host_async"):
+                    a.copy_to_host_async()
+            sc = np.asarray(sout)[:self.b]
+            chosen = np.asarray(chosen)[:, :, 0].T          # [k_steps, B]
         fused.STATS["batched_chunks"] = fused.STATS.get("batched_chunks", 0) + 1
-        chosen = np.asarray(chosen)[:, :, 0].T          # [k_steps, B]
         return (yout, sout), chosen, bool((sc[:, 1] > 0.5).all())
 
     def run_chunk(self, carry, k_steps: int):
@@ -781,18 +784,19 @@ def make_batched_runner(cfg: sim.StaticConfig, pbs: List, consts_list,
     if key in _failed_keys:
         return None
     if verify_against is not None and key not in _verified_keys:
-        v_consts, v_carry, steps, xla_run_chunk = verify_against
-        try:
-            _f_carry, f_chosen = runner.run_chunk(v_carry, steps)
-        except RuntimeFault:
-            raise
-        except Exception as e:
-            _mark_failed(runner, f"{type(e).__name__}: {e}", e)
-            return None
-        _x_carry, x_chosen = xla_run_chunk(cfg, v_consts, v_carry, steps)
-        if not np.array_equal(f_chosen, np.asarray(x_chosen)):
-            _mark_failed(runner, "cross-check divergence vs vmapped XLA")
-            return None
+        with obs.span("cc.verify"):
+            v_consts, v_carry, steps, xla_run_chunk = verify_against
+            try:
+                _f_carry, f_chosen = runner.run_chunk(v_carry, steps)
+            except RuntimeFault:
+                raise
+            except Exception as e:
+                _mark_failed(runner, f"{type(e).__name__}: {e}", e)
+                return None
+            _x_carry, x_chosen = xla_run_chunk(cfg, v_consts, v_carry, steps)
+            if not np.array_equal(f_chosen, np.asarray(x_chosen)):
+                _mark_failed(runner, "cross-check divergence vs vmapped XLA")
+                return None
         _verified_keys.add(key)
     return runner
 

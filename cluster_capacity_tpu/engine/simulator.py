@@ -29,6 +29,7 @@ from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
+from .. import obs
 from . import encode as enc
 from ..models.snapshot import IDX_CPU
 from ..ops import inter_pod_affinity as ipa_ops
@@ -809,48 +810,52 @@ def solve(pb: enc.EncodedProblem, max_limit: int = 0,
             explain=expl_obj)
 
     _ensure_x64(pb.profile)
-    cfg = cached_static_config(pb)
-    consts = cached_consts(pb)
-    carry = _init_carry(pb, consts, pb.profile.seed)
-    host_consts = consts
-    if mesh is not None:
-        from ..parallel import mesh as mesh_lib
-        consts = mesh_lib.shard_consts(mesh, consts)
-        carry = mesh_lib.shard_carry(mesh, carry)
-    run_chunk = _chunk_runner()
+    with obs.span("cc.setup"):
+        cfg = cached_static_config(pb)
+        consts = cached_consts(pb)
+        carry = _init_carry(pb, consts, pb.profile.seed)
+        host_consts = consts
+        if mesh is not None:
+            from ..parallel import mesh as mesh_lib
+            consts = mesh_lib.shard_consts(mesh, consts)
+            carry = mesh_lib.shard_carry(mesh, carry)
+        run_chunk = _chunk_runner()
 
-    budget = pb.max_steps_hint + 1
-    if max_limit and max_limit > 0:
-        budget = min(max_limit, budget)
-    budget = max(1, min(budget, _DEFAULT_UNLIMITED_CAP))
-    if bounds:
-        # right-size against the capacity upper bound (bounds/bracket.py,
-        # host f64 — same caps formula the fast path uses): the scan cannot
-        # place more than `upper` clones, so the final chunk stops wasting
-        # steps past saturation.  +1 keeps one step past the bound so the
-        # scan still discovers exhaustion and emits the FitError message.
-        from ..bounds.bracket import upper_bound_host
-        budget = max(1, min(budget, upper_bound_host(pb) + 1))
-    # Chunks always run at full length (steps no-op once stopped) so one
-    # compiled executable serves every solve of this shape; placements are
-    # trimmed to the budget afterwards.
-    chunk_size = min(chunk_size, budget)
+        budget = pb.max_steps_hint + 1
+        if max_limit and max_limit > 0:
+            budget = min(max_limit, budget)
+        budget = max(1, min(budget, _DEFAULT_UNLIMITED_CAP))
+        if bounds:
+            # right-size against the capacity upper bound (bounds/
+            # bracket.py, host f64 — same caps formula the fast path uses):
+            # the scan cannot place more than `upper` clones, so the final
+            # chunk stops wasting steps past saturation.  +1 keeps one step
+            # past the bound so the scan still discovers exhaustion and
+            # emits the FitError message.
+            from ..bounds.bracket import upper_bound_host
+            budget = max(1, min(budget, upper_bound_host(pb) + 1))
+        # Chunks always run at full length (steps no-op once stopped) so one
+        # compiled executable serves every solve of this shape; placements are
+        # trimmed to the budget afterwards.
+        chunk_size = min(chunk_size, budget)
 
-    # The fused Pallas kernel runs whole chunks in one device kernel when the
-    # config allows; its first min(48, budget) steps are cross-checked
-    # against the XLA step, and a divergence or compile/runtime failure
-    # raises a KernelFault on the chip (fused.mark_failed).  Between fused
-    # chunks the carry stays packed on device — only the chosen indices
-    # and the stop flag cross to the host.
-    from . import fused
-    explain = explain and mesh is None
-    fused_runner = None
-    if mesh is None and not explain:
-        # the Pallas kernel is single-device; meshes use XLA.  Explain also
-        # takes the XLA scan: the fused kernel's packed carry exposes no
-        # per-step score terms to attribute.
-        fused_runner = fused.make_runner(
-            cfg, pb, consts, verify_against=(consts, carry, min(48, budget)))
+        # The fused Pallas kernel runs whole chunks in one device kernel
+        # when the config allows; its first min(48, budget) steps are
+        # cross-checked against the XLA step, and a divergence or
+        # compile/runtime failure raises a KernelFault on the chip
+        # (fused.mark_failed).  Between fused chunks the carry stays packed
+        # on device — only the chosen indices and the stop flag cross to
+        # the host.
+        from . import fused
+        explain = explain and mesh is None
+        fused_runner = None
+        if mesh is None and not explain:
+            # the Pallas kernel is single-device; meshes use XLA.  Explain
+            # also takes the XLA scan: the fused kernel's packed carry
+            # exposes no per-step score terms to attribute.
+            fused_runner = fused.make_runner(
+                cfg, pb, consts,
+                verify_against=(consts, carry, min(48, budget)))
 
     placements: List[int] = []
     stopped = False
@@ -874,8 +879,9 @@ def solve(pb: enc.EncodedProblem, max_limit: int = 0,
         # initial carry on pure XLA (mark_failed bans the shape).  Keyed by
         # kernel shape AND problem content — different cluster data under
         # the same shape re-verifies.
-        verify_key = (fused_runner.pk.meta, fused_runner.interpret,
-                      fused.problem_fingerprint(pb))
+        with obs.span("cc.verify"):
+            verify_key = (fused_runner.pk.meta, fused_runner.interpret,
+                          fused.problem_fingerprint(pb))
         done_ckpts = fused._verified_windows.setdefault(verify_key, set())
         ckpts = [c for c in fused.verify_checkpoints(budget, fused_chunk)
                  if c not in done_ckpts]
@@ -884,7 +890,8 @@ def solve(pb: enc.EncodedProblem, max_limit: int = 0,
         diverged = False
         last_good = None
         try:
-            fused_state = fused_runner.pack(carry)
+            with obs.span("cc.setup"):
+                fused_state = fused_runner.pack(carry)
             last_good = fused_state
             inflight: deque = deque()
             issued = 0
@@ -907,17 +914,18 @@ def solve(pb: enc.EncodedProblem, max_limit: int = 0,
                     carry_v, ckpt = pending
                     pending = None
                     w_v = min(48, len(chosen))
-                    _xc, x_chosen = run_chunk(cfg, consts, carry_v, w_v)
-                    if not np.array_equal(np.asarray(x_chosen),
-                                          chosen[:w_v]):
-                        fused.mark_failed(
-                            fused_runner, "mid-solve cross-check divergence "
-                            f"at checkpoint step {ckpt}")
-                        diverged = True
-                        break
-                    done_ckpts.add(ckpt)
-                    fused.STATS["verified_windows"].append(
-                        (ckpt, fused_runner.pk.meta.n))
+                    with obs.span("cc.verify"):
+                        _xc, x_chosen = run_chunk(cfg, consts, carry_v, w_v)
+                        if not np.array_equal(np.asarray(x_chosen),
+                                              chosen[:w_v]):
+                            fused.mark_failed(
+                                fused_runner, "mid-solve cross-check "
+                                f"divergence at checkpoint step {ckpt}")
+                            diverged = True
+                            break
+                        done_ckpts.add(ckpt)
+                        fused.STATS["verified_windows"].append(
+                            (ckpt, fused_runner.pk.meta.n))
                 last_good = state_after
                 placements.extend(chosen[chosen >= 0].tolist())
                 steps_done += len(chosen)
@@ -955,25 +963,30 @@ def solve(pb: enc.EncodedProblem, max_limit: int = 0,
         static_code_dev = jnp.asarray(pb.static_code, dtype=jnp.int32)
         expl_state = _attr.init_state(carry)
         while not stopped and len(placements) < budget:
-            expl_state, (chosen, contribs) = run_explain(
-                cfg, consts, static_code_dev, expl_state, chunk_size)
+            with obs.span("cc.issue", steps=chunk_size, lanes=1):
+                expl_state, (chosen, contribs) = run_explain(
+                    cfg, consts, static_code_dev, expl_state, chunk_size)
             carry = expl_state.carry
-            stopped = bool(np.asarray(carry.stopped))
-            chosen = np.asarray(chosen)
+            with obs.span("cc.wait"):
+                stopped = bool(np.asarray(carry.stopped))
+                chosen = np.asarray(chosen)
             keep = chosen >= 0
             placements.extend(chosen[keep].tolist())
             why_rows.append(np.asarray(contribs)[keep])
     else:
         while not stopped and len(placements) < budget:
-            carry, chosen = run_chunk(cfg, consts, carry, chunk_size)
-            stopped = bool(np.asarray(carry.stopped))
-            chosen = np.asarray(chosen)
+            with obs.span("cc.issue", steps=chunk_size, lanes=1):
+                carry, chosen = run_chunk(cfg, consts, carry, chunk_size)
+            with obs.span("cc.wait"):
+                stopped = bool(np.asarray(carry.stopped))
+                chosen = np.asarray(chosen)
             placements.extend(chosen[chosen >= 0].tolist())
             if stopped:
                 break
     placements = placements[:budget]
     placed = len(placements)
-    stopped = bool(np.asarray(carry.stopped))
+    with obs.span("cc.wait"):
+        stopped = bool(np.asarray(carry.stopped))
 
     expl_obj = None
     if expl_state is not None:
@@ -1040,112 +1053,116 @@ def diagnose(pb: enc.EncodedProblem, cfg: StaticConfig, consts,
     the FitError reasons histogram (types.go:787-828).  Each infeasible node
     contributes the reason(s) of its first failing plugin in filter order; the
     fit plugin contributes every insufficient resource (fit.go:564-660)."""
-    feasible, parts = _feasibility(cfg, consts, carry, eanti_dyn=eanti_dyn,
-                                   ports_blocked=ports_blocked)
-    n = pb.snapshot.num_nodes
-    static_code = np.asarray(pb.static_code)
+    with obs.span("cc.diagnose"):
+        feasible, parts = _feasibility(cfg, consts, carry, eanti_dyn=eanti_dyn,
+                                       ports_blocked=ports_blocked)
+        n = pb.snapshot.num_nodes
+        static_code = np.asarray(pb.static_code)
 
-    fit = parts.get("fit")
-    fit_fail = ~np.asarray(fit.mask) if fit is not None else np.zeros(n, bool)
-    insufficient = np.asarray(fit.insufficient) if fit is not None else None
-    too_many = np.asarray(fit.too_many_pods) if fit is not None else None
-    ports_dyn_fail = ~np.asarray(parts["ports_dyn"]) if "ports_dyn" in parts \
-        else np.zeros(n, bool)
-    spread_ok = np.asarray(parts.get("spread_ok", np.ones(n, bool)))
-    spread_missing = np.asarray(parts.get("spread_missing", np.zeros(n, bool)))
-    if "ipa" in parts:
-        f_aff, f_anti, f_eanti = (np.asarray(x) for x in parts["ipa"])
-    else:
-        f_aff = f_anti = f_eanti = np.zeros(n, bool)
-
-    counts: Dict[str, int] = {}
-
-    def add(reason: str, k: int = 1):
-        if k:
-            counts[reason] = counts.get(reason, 0) + int(k)
-
-    # Vectorized first-fail attribution in plugin order.  `remaining` tracks
-    # nodes not yet attributed to an earlier plugin.
-    remaining = np.ones(n, dtype=bool)
-
-    # static (pre-fit) codes, incl. per-taint message strings
-    static_fail = static_code != enc.CODE_OK
-    for code in np.unique(static_code[static_fail]):
-        idxs = np.flatnonzero(static_code == code)
-        if int(code) == enc.CODE_TAINT:
-            for i in idxs:
-                add(pb.taint_reasons[i] or "node(s) had untolerated taint")
+        fit = parts.get("fit")
+        fit_fail = ~np.asarray(fit.mask) if fit is not None \
+            else np.zeros(n, bool)
+        insufficient = np.asarray(fit.insufficient) if fit is not None \
+            else None
+        too_many = np.asarray(fit.too_many_pods) if fit is not None else None
+        ports_dyn_fail = ~np.asarray(parts["ports_dyn"]) \
+            if "ports_dyn" in parts else np.zeros(n, bool)
+        spread_ok = np.asarray(parts.get("spread_ok", np.ones(n, bool)))
+        spread_missing = np.asarray(parts.get("spread_missing",
+                                              np.zeros(n, bool)))
+        if "ipa" in parts:
+            f_aff, f_anti, f_eanti = (np.asarray(x) for x in parts["ipa"])
         else:
-            add(enc.STATIC_REASONS[int(code)], len(idxs))
-    remaining &= ~static_fail
+            f_aff = f_anti = f_eanti = np.zeros(n, bool)
 
-    take = remaining & ports_dyn_fail
-    add(enc.STATIC_REASONS[enc.CODE_PORTS], int(take.sum()))
-    remaining &= ~take
+        counts: Dict[str, int] = {}
 
-    take = remaining & fit_fail
-    if take.any():
-        from ..ops.dynamic_resources import (DRA_RESOURCE_PREFIX,
-                                             REASON_CANNOT_ALLOCATE)
-        if too_many is not None:
-            add("Too many pods", int((take & too_many).sum()))
-        if insufficient is not None:
-            dra_cols = [j for j, rn in enumerate(pb.resource_names)
-                        if rn.startswith(DRA_RESOURCE_PREFIX)]
-            for j, rname in enumerate(pb.resource_names):
-                if j in dra_cols:
-                    continue
-                add(f"Insufficient {rname}",
-                    int((take & insufficient[:, j]).sum()))
-            if dra_cols:
-                dra_any = np.logical_or.reduce(
-                    [insufficient[:, j] for j in dra_cols])
-                add(REASON_CANNOT_ALLOCATE, int((take & dra_any).sum()))
-    remaining &= ~take
+        def add(reason: str, k: int = 1):
+            if k:
+                counts[reason] = counts.get(reason, 0) + int(k)
 
-    vol_fail = ~pb.volume_mask
-    take = remaining & vol_fail
-    for i in np.flatnonzero(take):
-        add(pb.volume_reasons[i] or "volume conflict")
-    remaining &= ~take
+        # Vectorized first-fail attribution in plugin order.  `remaining` tracks
+        # nodes not yet attributed to an earlier plugin.
+        remaining = np.ones(n, dtype=bool)
 
-    if cfg.volume_self_conflict \
-            and float(np.asarray(consts["vol_self_gate"])) > 0:
-        placed_np = np.asarray(carry.placed)
-        take = remaining & (placed_np > 0)
-        from ..ops.volumes import REASON_DISK_CONFLICT
-        add(REASON_DISK_CONFLICT, int(take.sum()))
-        remaining &= ~take
-    if cfg.rwop_self_conflict \
-            and float(np.asarray(consts["rwop_gate"])) > 0 \
-            and int(np.asarray(carry.placed_count)) > 0:
-        from ..ops.volumes import REASON_RWOP_CONFLICT
-        add(REASON_RWOP_CONFLICT, int(remaining.sum()))
-        remaining &= False
-    if cfg.dra_shared_colocate \
-            and float(np.asarray(consts["dra_colo_gate"])) > 0 \
-            and int(np.asarray(carry.placed_count)) > 0:
-        from ..ops.dynamic_resources import REASON_CANNOT_ALLOCATE
-        placed_np = np.asarray(carry.placed)
-        take = remaining & ~(placed_np > 0)
-        add(REASON_CANNOT_ALLOCATE, int(take.sum()))
+        # static (pre-fit) codes, incl. per-taint message strings
+        static_fail = static_code != enc.CODE_OK
+        for code in np.unique(static_code[static_fail]):
+            idxs = np.flatnonzero(static_code == code)
+            if int(code) == enc.CODE_TAINT:
+                for i in idxs:
+                    add(pb.taint_reasons[i] or "node(s) had untolerated taint")
+            else:
+                add(enc.STATIC_REASONS[int(code)], len(idxs))
+        remaining &= ~static_fail
+
+        take = remaining & ports_dyn_fail
+        add(enc.STATIC_REASONS[enc.CODE_PORTS], int(take.sum()))
         remaining &= ~take
 
-    take = remaining & spread_missing
-    add(enc.STATIC_REASONS[enc.CODE_SPREAD_MISSING_LABEL], int(take.sum()))
-    remaining &= ~take
-    take = remaining & ~spread_ok
-    add(enc.STATIC_REASONS[enc.CODE_SPREAD], int(take.sum()))
-    remaining &= ~take
-
-    for mask, code in ((f_aff, enc.CODE_IPA_AFFINITY),
-                       (f_anti, enc.CODE_IPA_ANTI),
-                       (f_eanti, enc.CODE_IPA_EXISTING_ANTI)):
-        take = remaining & mask
-        add(enc.STATIC_REASONS[code], int(take.sum()))
+        take = remaining & fit_fail
+        if take.any():
+            from ..ops.dynamic_resources import (DRA_RESOURCE_PREFIX,
+                                                 REASON_CANNOT_ALLOCATE)
+            if too_many is not None:
+                add("Too many pods", int((take & too_many).sum()))
+            if insufficient is not None:
+                dra_cols = [j for j, rn in enumerate(pb.resource_names)
+                            if rn.startswith(DRA_RESOURCE_PREFIX)]
+                for j, rname in enumerate(pb.resource_names):
+                    if j in dra_cols:
+                        continue
+                    add(f"Insufficient {rname}",
+                        int((take & insufficient[:, j]).sum()))
+                if dra_cols:
+                    dra_any = np.logical_or.reduce(
+                        [insufficient[:, j] for j in dra_cols])
+                    add(REASON_CANNOT_ALLOCATE, int((take & dra_any).sum()))
         remaining &= ~take
 
-    return counts
+        vol_fail = ~pb.volume_mask
+        take = remaining & vol_fail
+        for i in np.flatnonzero(take):
+            add(pb.volume_reasons[i] or "volume conflict")
+        remaining &= ~take
+
+        if cfg.volume_self_conflict \
+                and float(np.asarray(consts["vol_self_gate"])) > 0:
+            placed_np = np.asarray(carry.placed)
+            take = remaining & (placed_np > 0)
+            from ..ops.volumes import REASON_DISK_CONFLICT
+            add(REASON_DISK_CONFLICT, int(take.sum()))
+            remaining &= ~take
+        if cfg.rwop_self_conflict \
+                and float(np.asarray(consts["rwop_gate"])) > 0 \
+                and int(np.asarray(carry.placed_count)) > 0:
+            from ..ops.volumes import REASON_RWOP_CONFLICT
+            add(REASON_RWOP_CONFLICT, int(remaining.sum()))
+            remaining &= False
+        if cfg.dra_shared_colocate \
+                and float(np.asarray(consts["dra_colo_gate"])) > 0 \
+                and int(np.asarray(carry.placed_count)) > 0:
+            from ..ops.dynamic_resources import REASON_CANNOT_ALLOCATE
+            placed_np = np.asarray(carry.placed)
+            take = remaining & ~(placed_np > 0)
+            add(REASON_CANNOT_ALLOCATE, int(take.sum()))
+            remaining &= ~take
+
+        take = remaining & spread_missing
+        add(enc.STATIC_REASONS[enc.CODE_SPREAD_MISSING_LABEL], int(take.sum()))
+        remaining &= ~take
+        take = remaining & ~spread_ok
+        add(enc.STATIC_REASONS[enc.CODE_SPREAD], int(take.sum()))
+        remaining &= ~take
+
+        for mask, code in ((f_aff, enc.CODE_IPA_AFFINITY),
+                           (f_anti, enc.CODE_IPA_ANTI),
+                           (f_eanti, enc.CODE_IPA_EXISTING_ANTI)):
+            take = remaining & mask
+            add(enc.STATIC_REASONS[code], int(take.sum()))
+            remaining &= ~take
+
+        return counts
 
 
 def format_fit_error(num_nodes: int, counts: Dict[str, int]) -> str:

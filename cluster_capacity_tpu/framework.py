@@ -131,10 +131,10 @@ class ClusterCapacity:
         import time
 
         from .utils import metrics
-        from .utils.trace import (SPAN_SNAPSHOT, SPAN_SOLVE, default_tracer)
+        from .utils.trace import SPAN_SOLVE, default_tracer
         t0 = time.perf_counter()
-        with default_tracer.span(SPAN_SOLVE), default_tracer.profile():
-            self._result = self._solve_with_preemption(default_tracer)
+        with default_tracer.span(SPAN_SOLVE):
+            self._result = self._solve_with_preemption()
         reg = metrics.default_registry
         reg.inc(metrics.SCHEDULE_ATTEMPTS, amount=self._result.placed_count,
                 result="scheduled", profile=self.profile.name)
@@ -144,14 +144,13 @@ class ClusterCapacity:
         reg.observe(metrics.SCHEDULING_DURATION, time.perf_counter() - t0)
         return self._result
 
-    def _solve_with_preemption(self, tracer) -> SolveResult:
+    def _solve_with_preemption(self) -> SolveResult:
         """Batched solve + the DefaultPreemption PostFilter loop: when a cycle
         ends Unschedulable and victims exist, evict them and resume
         (engine/preemption.py; preemption.go:234)."""
         from .engine.preemption import (evaluate, format_preemption_message,
                                         may_find_victims)
         from .models.podspec import make_clone
-        from .utils.trace import SPAN_SNAPSHOT
 
         snapshot = self.snapshot
         profile = self.profile
@@ -166,8 +165,7 @@ class ClusterCapacity:
         cycle_results: List[SolveResult] = []   # rung/degraded provenance
 
         while True:
-            with tracer.span(SPAN_SNAPSHOT):
-                problem = encode_problem(snap, self.pod, profile)
+            problem = encode_problem(snap, self.pod, profile)
             remaining = (self.max_limit - len(placements)) \
                 if self.max_limit else 0
             if self.max_limit and remaining <= 0:

@@ -9,8 +9,9 @@ boundary irgate's GD001 audit proves every device call crosses):
 2. spans — nested, bounded, always-on (obs/spans.py), exported as
    Chrome-trace-event/Perfetto JSONL (obs/export.py);
 3. CLI surfaces — `--metrics-dump` (Prometheus text) and `--trace-out`
-   (trace JSONL) on both CLIs, plus the jax.profiler bridge that
-   utils/trace.Tracer already carries for deep dives.
+   (trace JSONL) on both CLIs; every span is also a jax.profiler
+   annotation, so a profiler trace (`--profile-out`) shows the program's
+   host layers beside the device ops.
 
 The deep-profiling layer (PR 9) builds three more surfaces on the same tap:
 obs/profile.py (device-time/memory attribution + jax.profiler capture),

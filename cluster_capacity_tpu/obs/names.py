@@ -17,10 +17,6 @@ Counters:
     cc_trace_spans_dropped_total                  span-buffer overflow
     cc_explains_total{rung}                       attribution artifacts built
         per solve rung (explain/artifacts.build_explanation)
-    cc_device_seconds_total{site,rung,phase}      accumulated guarded-dispatch
-        seconds — the device-time attribution surface (obs/profile.py); on
-        CPU fallback this is wall time inside the guard, on TPU it tracks
-        device occupancy because dispatch is serialized through guard.run
     cc_flight_bundles_total{code}                 flight-recorder bundles
         dumped per fault code (obs/flight.py)
     cc_serve_requests_total{outcome}              daemon answers by outcome:
@@ -70,7 +66,6 @@ SWEEP_GROUPS = "cc_sweep_groups"
 SCENARIOS = "cc_resilience_scenarios"
 EXPLAINS = "cc_explains_total"
 EXPLAIN_REASON_NODES = "cc_explain_reason_nodes"
-DEVICE_SECONDS = "cc_device_seconds_total"
 DEVICE_PEAK_BYTES = "cc_device_peak_bytes"
 KERNEL_EFFICIENCY = "cc_kernel_efficiency"
 FLIGHT_BUNDLES = "cc_flight_bundles_total"

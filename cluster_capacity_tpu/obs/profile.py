@@ -2,19 +2,19 @@
 
 Three surfaces, all fed from the guard.run choke point:
 
-1. per-dispatch accounting — ``guard_span`` (obs/spans.py) accumulates
-   ``cc_device_seconds_total{site,rung,phase}`` for every guarded call and,
-   when memory sampling is on, asks this module to sample the backend's
+1. per-dispatch sampling — when memory sampling is on, ``guard_span``
+   (obs/spans.py) asks this module to sample the backend's
    ``device.memory_stats()`` watermark into ``cc_device_peak_bytes`` and the
    span's attrs (so watermarks ride into the trace JSONL for free);
 2. aggregation — ``attribution()`` folds the span buffer into site × rung ×
-   phase rows (calls, device seconds, compile seconds, batch volume, fault
-   count, peak bytes) and ``render_attribution()`` prints the table the
-   ``hypercc profile`` subcommand shows;
+   phase rows (calls, guarded host wall seconds, compile seconds, batch
+   volume, fault count, peak bytes) and ``render_attribution()`` prints the
+   table the ``hypercc profile`` subcommand shows;
 3. capture — ``capture(out_dir)`` wraps ``jax.profiler`` start/stop so a
-   scenario can run under a real profiler trace; it degrades to a no-op when
-   the profiler is unavailable and always enables memory sampling for the
-   block.
+   scenario can run under a real profiler trace, with the program's
+   ``cc.`` spans (obs/spans.py) beside the device ops; it degrades to a
+   no-op when the profiler is unavailable and always enables memory
+   sampling for the block.
 
 Import discipline: jax is only imported lazily inside functions, and only
 its host-side device APIs are touched (``memory_stats`` is a host query —
@@ -99,9 +99,12 @@ def capture(out_dir: Optional[str] = None, *, memory: bool = True):
     """Run a block under programmatic jax.profiler capture.
 
     ``out_dir`` is the profiler trace directory (created if missing); pass
-    None to skip the profiler and only enable watermark sampling.  Profiler
-    failures (unavailable backend plugin, double-start) are reported to
-    stderr and swallowed — profiling must never take a solve down.
+    None to skip the profiler and only enable watermark sampling.  The
+    session records the device and the host's annotations (the program's
+    spans) and no Python call tracing, which keeps a trace of a long run
+    small.  Profiler failures (unavailable backend plugin, double-start)
+    are reported to stderr and swallowed — profiling must never take a
+    solve down.
     """
     started = False
     prev_mem = _sampling["memory"]
@@ -111,7 +114,10 @@ def capture(out_dir: Optional[str] = None, *, memory: bool = True):
         try:
             os.makedirs(out_dir, exist_ok=True)
             import jax
-            jax.profiler.start_trace(out_dir)
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            opts.host_tracer_level = 1
+            jax.profiler.start_trace(out_dir, profiler_options=opts)
             started = True
         except Exception as exc:
             sys.stderr.write(f"obs.profile: jax.profiler capture "

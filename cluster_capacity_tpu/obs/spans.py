@@ -9,6 +9,15 @@ ended it.  The collector is always on: a span costs two perf_counter reads
 and a dict, nothing here ever touches a jax value or forces a device sync,
 and the buffer is bounded (oldest spans drop, counted).
 
+Every span is also a `jax.profiler.TraceAnnotation` of the same name, its
+scalar attributes as the annotation's args, so whenever a profiler session
+runs (`--profile-out`, `hypercc profile`, a benchmark's traced run) the
+program's spans land in the `.xplane.pb` beside the device ops, on the
+same clock.  An annotation is host-only: with no session it costs well
+under a microsecond and records nothing.  jax is never imported from here:
+the annotation class is taken from an already-imported jax, and before jax
+is imported no session can be running.
+
 Rung inheritance: a span opened without an explicit rung inherits the
 nearest enclosing span's rung, so low-level dispatches inside a rung attempt
 are attributed to that rung without plumbing the string through every call.
@@ -23,7 +32,9 @@ codebase, so the last-opened sited span is the right owner.
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import sys
 import threading
 import time
 from dataclasses import dataclass, field
@@ -33,6 +44,34 @@ from ..utils import metrics as metrics_mod
 from . import names
 
 MAX_SPANS = 65536
+
+# jax.profiler.TraceAnnotation once jax is imported (None before)
+_annotation = None
+
+
+def _no_annotation(name: str, **args):
+    return contextlib.nullcontext()
+
+
+def _annotate():
+    global _annotation
+    if _annotation is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is None:
+            return _no_annotation
+        _annotation = profiler.TraceAnnotation
+    return _annotation
+
+
+def _scalar_args(sp: "Span") -> Dict[str, Any]:
+    args = {k: v for k, v in sp.attrs.items()
+            if isinstance(v, (int, float, str))}
+    for k in ("site", "rung", "phase"):
+        if getattr(sp, k):
+            args[k] = getattr(sp, k)
+    if sp.batch is not None:
+        args["batch"] = sp.batch
+    return args
 
 
 @dataclass
@@ -59,7 +98,8 @@ class Collector:
     def __init__(self, max_spans: int = MAX_SPANS):
         self.max_spans = max_spans
         self._lock = threading.Lock()
-        self._spans: List[Span] = []  # cc-guarded-by: _lock
+        self._spans: collections.deque = collections.deque(
+            maxlen=max_spans)  # cc-guarded-by: _lock
         self._local = threading.local()
         self._open_sited: List[Span] = []  # cc-guarded-by: _lock
         self._seen_sites: set = set()  # cc-guarded-by: _lock
@@ -102,32 +142,31 @@ class Collector:
                 if s.rung:
                     rung = s.rung
                     break
-        overflow = 0
         with self._lock:
             span_id = self._next_id
             self._next_id += 1
             first = bool(site) and site not in self._seen_sites
             if site:
                 self._seen_sites.add(site)
-            overflow = len(self._spans) - self.max_spans + 1
-            if overflow > 0:
-                del self._spans[:overflow]
-                self.dropped += overflow
-        if overflow > 0:
-            metrics_mod.default_registry.inc(names.SPANS_DROPPED, overflow)
         sp = Span(name=name, span_id=span_id,
                   parent_id=parent.span_id if parent else None,
                   thread_id=threading.get_ident(), start_s=time.time(),
                   site=site, rung=rung, phase=phase, batch=batch,
                   first_call=first, attrs=dict(attrs))
         with self._lock:
+            full = len(self._spans) == self.max_spans
+            if full:
+                self.dropped += 1
             self._spans.append(sp)
             if site:
                 self._open_sited.append(sp)
+        if full:
+            metrics_mod.default_registry.inc(names.SPANS_DROPPED)
         stack.append(sp)
         t0 = time.perf_counter()
         try:
-            yield sp
+            with _annotate()(name, **_scalar_args(sp)):
+                yield sp
             if not sp.outcome:
                 sp.outcome = "ok"
         except BaseException as exc:
@@ -184,7 +223,6 @@ def guard_span(*, site: str, phase: str, rung: str = "",
             lab = dict(site=site, rung=sp.rung or "-", phase=phase)
             reg.observe(names.GUARD_DURATION, dur, **lab)
             reg.inc(names.GUARD_RUNS, outcome=sp.outcome or "error", **lab)
-            reg.inc(names.DEVICE_SECONDS, dur, **lab)
             if sp.first_call:
                 reg.inc(names.GUARD_FIRST_CALLS, site=site)
             # memory-watermark sample (fast no-op unless profiling enabled

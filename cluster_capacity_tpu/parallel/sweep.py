@@ -24,6 +24,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from .. import obs
 from ..engine import encode as enc
 from ..engine import simulator as sim
 from ..models.snapshot import ClusterSnapshot
@@ -423,65 +424,70 @@ def _batched_solve(pbs: List[enc.EncodedProblem], max_limit: int,
                                       bounds=bounds))
         return out
 
-    sim._ensure_x64(pbs[0].profile)
-    pbs, cfg, dnh = _pad_group(pbs)
-    # Host-side consts/carry per template, stacked in numpy, ONE device
-    # transfer per key — not ~33 x B small transfers (the r4 profile showed
-    # per-template jnp.asarray + jnp.stack dominating the warm sweep).
-    consts_list = [sim.build_consts(pb, ss_dnh_min=dnh, device=False)
-                   for pb in pbs]
-    carry_list = [sim._init_carry(pb, c, pb.profile.seed, device=False)
-                  for pb, c in zip(pbs, consts_list)]
-    # Group dedup: consts identical across every template (the snapshot's
-    # allocatable, shared topology one-hots, ...) ride the vmapped step
-    # UNMAPPED — no B-way host stack, no B-way transfer, no B-way read per
-    # step.  Only genuinely per-template arrays stack.  (The mesh path keeps
-    # the full stacked layout: shard_consts shards the batch axis.)
-    n_nodes = pbs[0].snapshot.num_nodes
-    shared: Dict[str, "jax.Array"] = {}
-    if mesh is not None:
-        # full stacked layout, padded to the mesh's shard multiples (batch:
-        # duplicate templates, node: inert infeasible rows), then ONE
-        # sharded device_put per key — XLA's partitioner owns the layout
-        # from here and the scan never gathers a node table to one device.
-        stacked_np = {k: np.stack([c[k] for c in consts_list])
-                      for k in consts_list[0]}
-        carry_np = jax.tree.map(lambda *xs: np.stack(xs), *carry_list)
-        stacked_np, carry_np = mesh_lib.pad_for_mesh(mesh, stacked_np,
-                                                     carry_np)
-        stacked = mesh_lib.shard_consts(mesh, stacked_np, batched=True)
-        carry = mesh_lib.shard_carry(mesh, carry_np, batched=True)
-    else:
-        stacked = {}
-        for k in consts_list[0]:
-            arrs = [c[k] for c in consts_list]
-            if _group_uniform(arrs):
-                shared[k] = jnp.asarray(arrs[0])
-            else:
-                stacked[k] = jnp.asarray(np.stack(arrs))
-        carry = jax.tree.map(lambda *xs: jnp.asarray(np.stack(xs)),
-                             *carry_list)
-    consts = (shared, stacked)
+    with obs.span("cc.setup"):
+        sim._ensure_x64(pbs[0].profile)
+        pbs, cfg, dnh = _pad_group(pbs)
+        # Host-side consts/carry per template, stacked in numpy, ONE device
+        # transfer per key — not ~33 x B small transfers (the r4 profile
+        # showed per-template jnp.asarray + jnp.stack dominating the warm
+        # sweep).
+        consts_list = [sim.build_consts(pb, ss_dnh_min=dnh, device=False)
+                       for pb in pbs]
+        carry_list = [sim._init_carry(pb, c, pb.profile.seed, device=False)
+                      for pb, c in zip(pbs, consts_list)]
+        # Group dedup: consts identical across every template (the
+        # snapshot's allocatable, shared topology one-hots, ...) ride the
+        # vmapped step UNMAPPED — no B-way host stack, no B-way transfer, no
+        # B-way read per step.  Only genuinely per-template arrays stack.
+        # (The mesh path keeps the full stacked layout: shard_consts shards
+        # the batch axis.)
+        n_nodes = pbs[0].snapshot.num_nodes
+        shared: Dict[str, "jax.Array"] = {}
+        if mesh is not None:
+            # full stacked layout, padded to the mesh's shard multiples
+            # (batch: duplicate templates, node: inert infeasible rows), then
+            # ONE sharded device_put per key — XLA's partitioner owns the
+            # layout from here and the scan never gathers a node table to one
+            # device.
+            stacked_np = {k: np.stack([c[k] for c in consts_list])
+                          for k in consts_list[0]}
+            carry_np = jax.tree.map(lambda *xs: np.stack(xs), *carry_list)
+            stacked_np, carry_np = mesh_lib.pad_for_mesh(mesh, stacked_np,
+                                                         carry_np)
+            stacked = mesh_lib.shard_consts(mesh, stacked_np, batched=True)
+            carry = mesh_lib.shard_carry(mesh, carry_np, batched=True)
+        else:
+            stacked = {}
+            for k in consts_list[0]:
+                arrs = [c[k] for c in consts_list]
+                if _group_uniform(arrs):
+                    shared[k] = jnp.asarray(arrs[0])
+                else:
+                    stacked[k] = jnp.asarray(np.stack(arrs))
+            carry = jax.tree.map(lambda *xs: jnp.asarray(np.stack(xs)),
+                                 *carry_list)
+        consts = (shared, stacked)
 
-    if bounds:
-        # right-size the group budget from the per-template capacity upper
-        # bounds (bounds/bracket.py, host f64): the group scans until its
-        # LAST template saturates, so the max over (hint, bound)-clamped
-        # per-template budgets shaves every step past the slowest template's
-        # provable saturation.  +1 keeps the exhaustion-discovery step.
-        from ..bounds.bracket import upper_bound_host
-        budget = max(min(pb.max_steps_hint, upper_bound_host(pb))
-                     for pb in pbs) + 1
-    else:
-        budget = max(pb.max_steps_hint for pb in pbs) + 1
-    if max_limit and max_limit > 0:
-        budget = min(max_limit, budget)
-    budget = max(1, min(budget, sim._DEFAULT_UNLIMITED_CAP))
+        if bounds:
+            # right-size the group budget from the per-template capacity
+            # upper bounds (bounds/bracket.py, host f64): the group scans
+            # until its LAST template saturates, so the max over (hint,
+            # bound)-clamped per-template budgets shaves every step past the
+            # slowest template's provable saturation.  +1 keeps the
+            # exhaustion-discovery step.
+            from ..bounds.bracket import upper_bound_host
+            budget = max(min(pb.max_steps_hint, upper_bound_host(pb))
+                         for pb in pbs) + 1
+        else:
+            budget = max(pb.max_steps_hint for pb in pbs) + 1
+        if max_limit and max_limit > 0:
+            budget = min(max_limit, budget)
+        budget = max(1, min(budget, sim._DEFAULT_UNLIMITED_CAP))
 
-    if mesh is not None:
-        run_chunk = _batched_chunk_runner_sharded(mesh, consts, carry)
-    else:
-        run_chunk = _batched_chunk_runner()
+        if mesh is not None:
+            run_chunk = _batched_chunk_runner_sharded(mesh, consts, carry)
+        else:
+            run_chunk = _batched_chunk_runner()
 
     if lower_only:
         # Static-analysis escape hatch (tools/shardgate): hand back the
@@ -508,9 +514,10 @@ def _batched_solve(pbs: List[enc.EncodedProblem], max_limit: int,
     # XLA step; divergence or compile failure falls back for this group.
     bfused = None
     if mesh is None:
-        bfused = fused_batched.make_batched_runner(
-            cfg, pbs, consts_list, max_dnh=dnh,
-            verify_against=(consts, carry, min(48, budget), run_chunk))
+        with obs.span("cc.setup"):
+            bfused = fused_batched.make_batched_runner(
+                cfg, pbs, consts_list, max_dnh=dnh,
+                verify_against=(consts, carry, min(48, budget), run_chunk))
 
     placements: List[List[int]] = [[] for _ in pbs]
     steps_done = 0
@@ -529,7 +536,8 @@ def _batched_solve(pbs: List[enc.EncodedProblem], max_limit: int,
         if bfused is not None:
             try:
                 if bstate is None:
-                    bstate = bfused.pack(carry)
+                    with obs.span("cc.setup"):
+                        bstate = bfused.pack(carry)
                 bstate, chosen, all_stopped = bfused.run_packed(bstate, chunk)
             except Exception as e:
                 # Lazy Mosaic compile/runtime failure: raises on the chip
@@ -546,9 +554,11 @@ def _batched_solve(pbs: List[enc.EncodedProblem], max_limit: int,
                 bstate = None
                 continue
         else:
-            carry, chosen = run_chunk(cfg, consts, carry, chunk)  # [n, B]
-            chosen = np.asarray(chosen)
-            all_stopped = bool(np.all(np.asarray(carry.stopped)))
+            with obs.span("cc.issue", steps=chunk, lanes=len(pbs)):
+                carry, chosen = run_chunk(cfg, consts, carry, chunk)
+            with obs.span("cc.wait"):
+                chosen = np.asarray(chosen)                       # [n, B]
+                all_stopped = bool(np.all(np.asarray(carry.stopped)))
         for b in range(len(pbs)):
             col = chosen[:, b]
             placements[b].extend(col[col >= 0].tolist())
@@ -573,14 +583,16 @@ def _batched_solve(pbs: List[enc.EncodedProblem], max_limit: int,
         # only when some template actually stopped short of its limit and
         # needs the carry for diagnose(), or explain needs terminal codes;
         # pure limit-reached sweeps skip it.
-        stopped = bfused.stopped_flags(bstate)
+        with obs.span("cc.wait"):
+            stopped = bfused.stopped_flags(bstate)
         if explain or any(bool(stopped[b])
                           and not (max_limit
                                    and len(placements[b]) >= max_limit)
                           for b in range(len(pbs))):
             carry = bfused.unpack(bstate, carry)
     else:
-        stopped = np.asarray(carry.stopped)
+        with obs.span("cc.wait"):
+            stopped = np.asarray(carry.stopped)
 
     def _explain_b(pb, b):
         # Why-not from this template's slice of the batched terminal carry:

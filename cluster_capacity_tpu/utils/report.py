@@ -161,6 +161,12 @@ def build_review(templates: List[dict], results) -> ClusterCapacityReview:
     """Build the review from SolveResults (engine/simulator.py) — one result
     per template, aligned by index.  A single result is accepted for the
     single-template case."""
+    from ..obs.spans import span
+    with span("cc.report"):
+        return _build_review(templates, results)
+
+
+def _build_review(templates: List[dict], results) -> ClusterCapacityReview:
     if not isinstance(results, (list, tuple)):
         results = [results]
     if len(results) != len(templates):

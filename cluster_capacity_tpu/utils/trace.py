@@ -1,80 +1,40 @@
-"""Tracing spans.
+"""The `--trace` stderr printer.
 
-The reference wraps each scheduling cycle in utiltrace spans with a 100 ms
-log threshold ("Snapshotting scheduler cache and node infos done", "Computing
-predicates done", "Prioritizing done" — vendor/.../schedule_one.go:431-471).
-Here a solve is one batched computation, so spans cover the analogous phases:
-snapshot encode, device transfer + compile, and the scan itself.  Enable with
-`--trace` on the CLI or trace.enable(); optionally bridges to jax.profiler.
+The reference wraps each scheduling cycle in utiltrace spans
+("Snapshotting scheduler cache and node infos done" —
+vendor/.../schedule_one.go:431-471).  Here the coarse phases are the
+snapshot build and the solve: each is a span of the obs/ collector (so
+`--trace-out` and profiler traces carry it), and with `--trace` its
+duration is printed to stderr as it closes.
 """
 
 from __future__ import annotations
 
 import contextlib
 import sys
-import time
-from dataclasses import dataclass, field
-from typing import List, Optional
+from dataclasses import dataclass
 
 SPAN_SNAPSHOT = "Snapshotting cluster state into device tensors"
-SPAN_PREDICATES = "Computing predicates"
-SPAN_PRIORITIES = "Prioritizing"
 SPAN_SOLVE = "Running placement scan"
-
-
-@dataclass
-class Span:
-    name: str
-    start: float
-    duration: Optional[float] = None
 
 
 @dataclass
 class Tracer:
     enabled: bool = False
-    threshold_s: float = 0.0   # reference logs spans above 100 ms
-    spans: List[Span] = field(default_factory=list)
-    jax_profile_dir: Optional[str] = None
 
-    def enable(self, threshold_s: float = 0.0,
-               jax_profile_dir: Optional[str] = None) -> None:
+    def enable(self) -> None:
         self.enabled = True
-        self.threshold_s = threshold_s
-        self.jax_profile_dir = jax_profile_dir
 
     @contextlib.contextmanager
     def span(self, name: str):
-        # Always feed the obs/ span collector (it is bounded and ~free), so
-        # --trace-out captures the snapshot/solve phase spans even when the
-        # stderr printer below is off; the Tracer's own list + printing stay
-        # gated on enable() as before.
         from ..obs.spans import default_collector
-        if not self.enabled:
-            with default_collector.span(name):
-                yield
-            return
-        s = Span(name=name, start=time.perf_counter())
-        if len(self.spans) >= 1000:        # bound long-lived processes
-            del self.spans[:500]
-        self.spans.append(s)
         try:
-            with default_collector.span(name):
+            with default_collector.span(name) as sp:
                 yield
         finally:
-            s.duration = time.perf_counter() - s.start
-            if s.duration >= self.threshold_s:
-                print(f'Trace: "{name}" took {s.duration * 1000:.1f}ms',
+            if self.enabled:
+                print(f'Trace: "{name}" took {sp.duration_s * 1000:.1f}ms',
                       file=sys.stderr)
-
-    @contextlib.contextmanager
-    def profile(self):
-        """Wrap a region in a jax.profiler trace when a dump dir is set."""
-        if not self.enabled or not self.jax_profile_dir:
-            yield
-            return
-        import jax
-        with jax.profiler.trace(self.jax_profile_dir):
-            yield
 
 
 default_tracer = Tracer()

@@ -135,6 +135,164 @@ def test_guard_span_outcome_and_histogram():
     assert default_registry.histograms[key].count == 1
 
 
+def test_span_buffer_at_cap_keeps_newest_and_counts_drops():
+    c = obs.Collector(max_spans=8)
+    for i in range(20):
+        with c.span(f"outer{i}"):
+            with c.span(f"inner{i}"):
+                pass
+    assert [s.name for s in c.spans()] == [
+        f"{kind}{i}" for i in range(16, 20) for kind in ("outer", "inner")]
+    assert c.dropped == 32
+    assert default_registry.counter_total(obs_names.SPANS_DROPPED) == 32
+    c.reset()
+    assert c.spans() == [] and c.dropped == 0
+
+
+def _profiler_events(trace_dir):
+    """{name: [ProfileEvent]} of the host events named cc.* in the one
+    .xplane.pb a jax.profiler session wrote under `trace_dir`."""
+    import glob
+
+    from jax.profiler import ProfileData
+    [path] = glob.glob(os.path.join(str(trace_dir), "plugins", "profile",
+                                    "*", "*.xplane.pb"))
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("cc."):
+                    out.setdefault(ev.name, []).append(ev)
+    return out
+
+
+def test_span_lands_in_the_profiler_trace_with_its_args(tmp_path):
+    import jax
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.span("cc.outer", steps=7, lanes=2, site="t.site"):
+            with obs.span("cc.inner", note="x", skipped=[1, 2]):
+                pass
+    events = _profiler_events(tmp_path)
+    [outer], [inner] = events["cc.outer"], events["cc.inner"]
+    assert dict(outer.stats) == {"steps": 7, "lanes": 2, "site": "t.site"}
+    assert dict(inner.stats) == {"note": "x"}     # scalars only
+    assert outer.start_ns <= inner.start_ns
+    assert inner.start_ns + inner.duration_ns <= \
+        outer.start_ns + outer.duration_ns
+    # the collector keeps them too, nested
+    spans = {s.name: s for s in obs.default_collector.spans()}
+    assert spans["cc.inner"].parent_id == spans["cc.outer"].span_id
+
+
+def test_span_without_a_profiler_session_is_still_collected(monkeypatch):
+    from cluster_capacity_tpu.obs import spans as spans_mod
+    c = obs.Collector()
+    with c.span("cc.issue", steps=3, lanes=1):
+        pass
+    # before jax is imported no session can run: no annotation at all
+    monkeypatch.setattr(spans_mod, "_annotation", None)
+    monkeypatch.delitem(sys.modules, "jax")
+    with c.span("cc.wait", site="t.site", batch=2, note="x"):
+        pass
+    assert spans_mod._annotation is None
+    issue, wait = c.spans()
+    assert (issue.name, issue.attrs) == ("cc.issue", {"steps": 3, "lanes": 1})
+    assert wait.name == "cc.wait" and wait.outcome == "ok"
+    assert (wait.site, wait.batch, wait.attrs) == ("t.site", 2, {"note": "x"})
+    assert issue.duration_s >= 0.0 and wait.duration_s >= 0.0
+
+
+def _zoned_snapshot(n=8):
+    nodes = [build_test_node(
+        f"n{i}", 2000, 4 * 1024 ** 3, 8,
+        labels={"kubernetes.io/hostname": f"n{i}",
+                "topology.kubernetes.io/zone": f"z{i % 2}"})
+        for i in range(n)]
+    return ClusterSnapshot.from_objects(nodes)
+
+
+def _spread_pod(name, cpu_milli):
+    pod = build_test_pod(name, cpu_milli, labels={"app": name})
+    pod["spec"]["topologySpreadConstraints"] = [
+        {"maxSkew": 1, "topologyKey": "topology.kubernetes.io/zone",
+         "whenUnsatisfiable": "DoNotSchedule",
+         "labelSelector": {"matchLabels": {"app": name}}}]
+    return default_pod(pod)
+
+
+def _cc_spans():
+    return [s for s in obs.default_collector.spans()
+            if s.name.startswith("cc.")]
+
+
+def test_scan_answer_emits_each_layer_span_in_order():
+    from cluster_capacity_tpu import ClusterCapacity
+    cc = ClusterCapacity(_spread_pod("spread", 500))
+    cc.set_snapshot(_zoned_snapshot())
+    cc.run()
+    review = cc.report()
+    assert review.replicas == 32 and review.fail_type == "Unschedulable"
+    spans = _cc_spans()
+    names = [s.name for s in spans]
+    first = {n: names.index(n) for n in reversed(names)}
+    last = {n: len(names) - 1 - names[::-1].index(n) for n in names}
+    assert first["cc.encode"] < first["cc.setup"] < first["cc.issue"] \
+        < first["cc.wait"] < first["cc.diagnose"] < first["cc.report"]
+    assert last["cc.wait"] < first["cc.diagnose"]
+    enc_span = spans[first["cc.encode"]]
+    inside = {s.name for s in spans if s.parent_id == enc_span.span_id}
+    assert inside == {"cc.encode.spread", "cc.encode.affinity"}
+    issues = [s for s in spans if s.name == "cc.issue"]
+    assert all(s.attrs["lanes"] == 1 and s.attrs["steps"] > 0
+               for s in issues)
+    assert sum(s.attrs["steps"] for s in issues) >= review.replicas
+    assert "cc.fast.sort" not in names
+
+
+def test_fast_path_answer_emits_its_sort_span():
+    from cluster_capacity_tpu import ClusterCapacity
+    cc = ClusterCapacity(default_pod(build_test_pod("plain", 500)),
+                         max_limit=5)
+    cc.set_snapshot(_zoned_snapshot())
+    cc.run()
+    assert cc.report().replicas == 5
+    names = [s.name for s in _cc_spans()]
+    for name in ("cc.encode", "cc.setup", "cc.issue", "cc.wait",
+                 "cc.fast.sort", "cc.report"):
+        assert name in names
+    assert names.index("cc.wait") < names.index("cc.fast.sort")
+
+
+def test_batched_sweep_issue_carries_the_group_lanes():
+    from cluster_capacity_tpu.parallel.sweep import sweep
+    results = sweep(_zoned_snapshot(),
+                    [_spread_pod("a", 500), _spread_pod("b", 700)])
+    assert [r.placed_count for r in results] == [32, 16]
+    spans = _cc_spans()
+    issues = [s for s in spans if s.name == "cc.issue"]
+    assert issues and all(s.attrs["lanes"] == 2 for s in issues)
+    names = [s.name for s in spans]
+    assert names.count("cc.encode") == 2
+    assert names.count("cc.diagnose") == 2
+
+
+def test_trace_flag_prints_the_snapshot_and_solve_phases(monkeypatch,
+                                                         capsys):
+    from cluster_capacity_tpu.cli.cluster_capacity import run
+    from cluster_capacity_tpu.utils import trace
+    monkeypatch.setattr(trace.default_tracer, "enabled", False)
+    rc = run(["--podspec", os.path.join(ROOT, "examples", "pod.yaml"),
+              "--snapshot", os.path.join(ROOT, "examples",
+                                         "cluster-snapshot.yaml"),
+              "--trace"])
+    assert rc == 0
+    err = capsys.readouterr().err
+    assert f'Trace: "{trace.SPAN_SNAPSHOT}" took ' in err
+    assert f'Trace: "{trace.SPAN_SOLVE}" took ' in err
+    names = {s.name for s in obs.default_collector.spans()}
+    assert {trace.SPAN_SNAPSHOT, trace.SPAN_SOLVE, "cc.encode"} <= names
+
+
 # --- metrics rendering -------------------------------------------------------
 
 def test_prometheus_render_golden():

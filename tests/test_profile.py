@@ -155,8 +155,8 @@ def test_write_calibration_atomic(tmp_path):
 
 def test_guarded_dispatch_accumulates_attribution_rows():
     """A degraded solve leaves one attribution row per site × rung × phase
-    with the fault counted on the failing site, and the device-seconds
-    counter grows with the same labels."""
+    with the fault counted on the failing site, and the guard's duration
+    histogram grows with the same labels."""
     with faults.inject("engine.solve:oom"):
         res = degrade.solve_one_guarded(_pb())
     assert res.degraded
@@ -170,7 +170,10 @@ def test_guarded_dispatch_accumulates_attribution_rows():
     for r in rows:
         assert r["calls"] >= 1 and r["device_s"] >= 0.0
 
-    assert default_registry.counter_total(obs_names.DEVICE_SECONDS) > 0.0
+    guarded_s = sum(h.total for (name, _), h
+                    in default_registry.histograms.items()
+                    if name == obs_names.GUARD_DURATION)
+    assert guarded_s > 0.0
     summary = obs_profile.device_summary()
     assert summary["device_s"] == pytest.approx(
         sum(r["device_s"] for r in rows), abs=1e-6)
