@@ -877,14 +877,18 @@ def solve(pb: enc.EncodedProblem, max_limit: int = 0,
         # snapshot.  A divergence proves the kernel wrong somewhere, so
         # EVERYTHING it produced is suspect: the solve restarts from the
         # initial carry on pure XLA (mark_failed bans the shape).  Keyed by
-        # kernel shape AND problem content — different cluster data under
-        # the same shape re-verifies.
-        with obs.span("cc.verify"):
+        # kernel shape AND the kernel's and the XLA step's inputs (consts,
+        # initial carry, static config) — different cluster data under the
+        # same shape re-verifies; a re-encode of the same cluster does not.
+        # `due` counts the checkpoints this solve still has to verify.
+        with obs.span("cc.verify") as sp:
             verify_key = (fused_runner.pk.meta, fused_runner.interpret,
-                          fused.problem_fingerprint(pb))
-        done_ckpts = fused._verified_windows.setdefault(verify_key, set())
-        ckpts = [c for c in fused.verify_checkpoints(budget, fused_chunk)
-                 if c not in done_ckpts]
+                          fused.kernel_input_fingerprint(cfg, pb))
+            done_ckpts = fused._verified_windows.setdefault(verify_key,
+                                                            set())
+            ckpts = [c for c in fused.verify_checkpoints(budget, fused_chunk)
+                     if c not in done_ckpts]
+            sp.attrs["due"] = len(ckpts)
         pending = None          # (carry at snapshot, checkpoint step)
         carry0 = carry
         diverged = False

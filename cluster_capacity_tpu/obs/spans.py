@@ -165,8 +165,12 @@ class Collector:
         stack.append(sp)
         t0 = time.perf_counter()
         try:
-            with _annotate()(name, **_scalar_args(sp)):
+            args = _scalar_args(sp)
+            with _annotate()(name, **args) as ann:
                 yield sp
+                # attributes set on the open span reach the trace too
+                if ann is not None and (late := _scalar_args(sp)) != args:
+                    ann.set_metadata(**late)
             if not sp.outcome:
                 sp.outcome = "ok"
         except BaseException as exc:

@@ -475,7 +475,7 @@ def test_midsolve_checkpoint_verifies(monkeypatch):
     sim.solve(pb, max_limit=200, chunk_size=32)
     assert fused.STATS["verified_windows"][before:] == []
     # same kernel shape but DIFFERENT cluster data: must re-verify (the
-    # memo key includes a problem fingerprint, review-found gap)
+    # memo key includes a fingerprint of the kernel's inputs)
     nodes2 = _nodes(6, seed=12)
     snap2 = ClusterSnapshot.from_objects(nodes2)
     pod2 = {"metadata": {"name": "p", "labels": {"app": "ck"}},
@@ -521,3 +521,160 @@ def test_midsolve_divergence_falls_back(monkeypatch):
     assert r1.placements == r2.placements
     assert r1.fail_message == r2.fail_message
     fused._failed_metas.clear()
+
+
+# ---------------------------------------------------------------------------
+# The verification memo's key: a fingerprint of the kernel's inputs
+# ---------------------------------------------------------------------------
+
+def _fp_objects():
+    """Six zoned nodes, three resident pods (app=other) and a template with
+    a zonal DoNotSchedule spread over app=ck: (nodes, pods, template)."""
+    nodes = _nodes(6, seed=11, zones=2)
+    pods = [{"metadata": {"name": f"r{i}", "namespace": "default",
+                          "labels": {"app": "other"}},
+             "spec": {"nodeName": f"node-{i:04d}", "containers": [{
+                 "name": "c", "resources": {"requests": {"cpu": "100m"}}}]}}
+            for i in range(3)]
+    pod = {"metadata": {"name": "p", "labels": {"app": "ck"}},
+           "spec": {"containers": [{"name": "c", "resources": {"requests": {
+               "cpu": "10m"}}}],
+               "topologySpreadConstraints": [{
+                   "maxSkew": 4, "topologyKey": "topology.kubernetes.io/zone",
+                   "whenUnsatisfiable": "DoNotSchedule",
+                   "labelSelector": {"matchLabels": {"app": "ck"}}}]}}
+    return nodes, pods, pod
+
+
+def _fp_problem(nodes, pods, pod, seed=0):
+    snap = ClusterSnapshot.from_objects(nodes, pods)
+    return enc.encode_problem(snap, default_pod(pod),
+                              SchedulerProfile(seed=seed))
+
+
+def _fp_digest(pb):
+    return fused.kernel_input_fingerprint(sim.cached_static_config(pb), pb)
+
+
+@pytest.fixture
+def two_checkpoints(monkeypatch):
+    """Fused kernel in interpret mode with 32-step chunks and checkpoints at
+    steps 32 and 96; returns solve(pb) -> the checkpoints it verified."""
+    monkeypatch.setenv("CC_TPU_FUSED", "1")
+    monkeypatch.setattr(sim, "_FUSED_CHUNK", 32)
+    monkeypatch.setattr(
+        fused, "verify_checkpoints",
+        lambda budget, chunk: tuple(c for c in (chunk, 96) if c < budget))
+    fused._verified_windows.clear()
+
+    def solve(pb):
+        before = len(fused.STATS["verified_windows"])
+        sim.solve(pb, max_limit=200, chunk_size=32)
+        return [c for c, _n in fused.STATS["verified_windows"][before:]]
+    return solve
+
+
+def test_reencoded_problem_finds_its_checkpoints_verified(two_checkpoints):
+    """The benchmark, `--period` and `--watch` re-encode the same cluster and
+    template into a new problem object for every answer: the second object
+    hashes the same and verifies no checkpoint again."""
+    nodes, pods, pod = _fp_objects()
+    snap = ClusterSnapshot.from_objects(nodes, pods)
+    pb1 = enc.encode_problem(snap, default_pod(pod), SchedulerProfile())
+    pb2 = enc.encode_problem(snap, default_pod(pod), SchedulerProfile())
+    assert pb1 is not pb2
+    assert two_checkpoints(pb1) == [32, 96]
+    assert _fp_digest(pb2) == _fp_digest(pb1)
+    assert two_checkpoints(pb2) == []
+
+
+def _set_alloc(nodes, pods):
+    nodes[4]["status"]["allocatable"]["cpu"] = "6000m"
+
+
+def _set_resident_request(nodes, pods):
+    pods[1]["spec"]["containers"][0]["resources"]["requests"]["cpu"] = "300m"
+
+
+def _set_resident_label(nodes, pods):
+    pods[2]["metadata"]["labels"]["app"] = "ck"
+
+
+def _set_seed(nodes, pods):
+    return 7
+
+
+@pytest.mark.parametrize("change", [
+    _set_alloc, _set_resident_request, _set_resident_label, _set_seed],
+    ids=["node_allocatable", "resident_request", "resident_spread_label",
+         "profile_seed"])
+def test_kernel_visible_change_reverifies(two_checkpoints, change):
+    """Every change the kernel can see gives a new digest, and the solve of
+    the re-encoded problem verifies its checkpoints again.  A change edits
+    the objects in place and may return a new profile seed."""
+    nodes, pods, pod = _fp_objects()
+    base = _fp_problem(nodes, pods, pod)
+    assert two_checkpoints(base) == [32, 96]
+    seed = change(nodes, pods) or 0
+    changed = _fp_problem(nodes, pods, pod, seed=seed)
+    assert _fp_digest(changed) != _fp_digest(base)
+    assert two_checkpoints(changed) == [32, 96]
+
+
+@pytest.mark.parametrize("change", ["rename", "annotate"])
+def test_kernel_invisible_change_keeps_digest(change):
+    """Pod names and annotations that nothing reads never reach the kernel:
+    the digest stays, so no checkpoint is paid for again."""
+    nodes, pods, pod = _fp_objects()
+    base = _fp_digest(_fp_problem(nodes, pods, pod))
+    if change == "rename":
+        pods[0]["metadata"]["name"] = "renamed"
+    else:
+        pods[0]["metadata"]["annotations"] = {"note": "read by nothing"}
+    assert _fp_digest(_fp_problem(nodes, pods, pod)) == base
+
+
+def _const_keys():
+    nodes, pods, pod = _fp_objects()
+    return sorted(sim.build_consts(_fp_problem(nodes, pods, pod),
+                                   device=False))
+
+
+@pytest.mark.parametrize("key", _const_keys())
+def test_every_const_is_in_the_digest(monkeypatch, key):
+    """Changing any one array of the host const dict changes the digest, so
+    a const added later is covered without an edit to the fingerprint."""
+    nodes, pods, pod = _fp_objects()
+    base = _fp_digest(_fp_problem(nodes, pods, pod))
+    build = sim.build_consts
+
+    def perturbed(pb, *a, **kw):
+        consts = dict(build(pb, *a, **kw))
+        arr = np.array(consts[key])
+        if arr.size == 0:
+            arr = np.zeros((1,) + arr.shape[1:], arr.dtype)
+        elif arr.dtype == bool:
+            arr.flat[0] = not arr.flat[0]
+        else:
+            arr.flat[0] += 1
+        consts[key] = arr
+        return consts
+    monkeypatch.setattr(sim, "build_consts", perturbed)
+    assert _fp_digest(_fp_problem(nodes, pods, pod)) != base
+
+
+def test_verify_lookup_span_counts_due_checkpoints(two_checkpoints):
+    """The `cc.verify` span around the memo lookup carries `due`, the
+    checkpoints still unverified for the key: both on the first solve,
+    none on the repeat."""
+    from cluster_capacity_tpu import obs
+
+    def due_of(pb):
+        obs.default_collector.reset()
+        two_checkpoints(pb)
+        return [s.attrs["due"] for s in obs.default_collector.spans()
+                if s.name == "cc.verify" and "due" in s.attrs]
+
+    pb = _fp_problem(*_fp_objects())
+    assert due_of(pb) == [2]
+    assert due_of(pb) == [0]

@@ -184,6 +184,19 @@ def test_span_lands_in_the_profiler_trace_with_its_args(tmp_path):
     assert spans["cc.inner"].parent_id == spans["cc.outer"].span_id
 
 
+def test_attribute_set_on_an_open_span_lands_in_the_trace(tmp_path):
+    """A count known only inside the span (cc.verify's `due`) reaches the
+    profiler's args as well as the collector."""
+    import jax
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.span("cc.late", steps=1) as sp:
+            sp.attrs["due"] = 2
+    [ev] = _profiler_events(tmp_path)["cc.late"]
+    assert dict(ev.stats) == {"steps": 1, "due": 2}
+    [late] = [s for s in obs.default_collector.spans() if s.name == "cc.late"]
+    assert late.attrs == {"steps": 1, "due": 2}
+
+
 def test_span_without_a_profiler_session_is_still_collected(monkeypatch):
     from cluster_capacity_tpu.obs import spans as spans_mod
     c = obs.Collector()
