@@ -1,20 +1,13 @@
-"""The control for `correct`: the plain reference put in the program's
-place with one of its stated properties broken.  It answers every
-question of the cell's catalogue on the cell's cluster, and its answers
-go through the same comparison as the program's.  The comparison must
-find it not correct.
+"""The control for `correct`: the configuration's plain reference put in
+the program's place with one of its stated properties broken.  It answers
+every question of the cell's catalogue on the cell's cluster, and its
+answers go through the same comparison as the program's.  The comparison
+must find it not correct.
 
 The traffic file names the control ("control"; "bfloat16" where it
-names none):
-
-- `bfloat16`: the reference computed in bfloat16, the precision below the
-  float32 the configurations state.
-- `namespaces_ignored`: the reference in float32 on a cluster and a
-  catalogue whose pod affinity terms have lost their `namespaces` lists,
-  so each term matches pods of its owner's namespace alone: the guarantee
-  that a term matches the namespaces it lists, broken.  For cells whose
-  every decision is a filter on whole counts and a tie among identical
-  nodes, where no precision changes an answer.
+names none): the file `controls/<name>.py`, whose `apply(pods,
+templates)` returns (resident pods, catalogue, dtype) for the reference
+to answer with.  Each control says in its docstring what it breaks.
 
     python benchmark/control.py --workload <name> --seeds 1,2,3
 
@@ -26,13 +19,10 @@ beside a benchmark run on the chip's host.
 from __future__ import annotations
 
 import argparse
-import copy
 import json
 import os
 import sys
 import time
-
-import numpy as np
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 if HERE not in sys.path:
@@ -41,30 +31,11 @@ if HERE not in sys.path:
 import compare  # noqa: E402
 import gen  # noqa: E402
 import harness  # noqa: E402
-import reference  # noqa: E402
-
-
-def _without_namespaces(pods: list) -> list:
-    """Copies of `pods` whose pod (anti-)affinity terms list no
-    namespaces."""
-    out = copy.deepcopy(pods)
-    for pod in out:
-        aff = (pod.get("spec") or {}).get("affinity") or {}
-        for kind in ("podAffinity", "podAntiAffinity"):
-            part = aff.get(kind) or {}
-            terms = list(part.get(
-                "requiredDuringSchedulingIgnoredDuringExecution") or [])
-            terms += [t["podAffinityTerm"] for t in part.get(
-                "preferredDuringSchedulingIgnoredDuringExecution") or []]
-            for t in terms:
-                t.pop("namespaces", None)
-    return out
 
 
 def readings(workload: str, seed: int, overrides: dict = None) -> dict:
     """The control's numbers for one seed: every template of the cell's
     catalogue answered by the control, compared as a run's answers are."""
-    import ml_dtypes
     with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
         c = harness.load_cell(json.load(f), workload)
     cfg, traffic = c["config"], c["traffic"]
@@ -73,19 +44,14 @@ def readings(workload: str, seed: int, overrides: dict = None) -> dict:
     cluster = gen.make_cluster(cfg, seed)
     templates = gen.templates(traffic)
     kind = traffic.get("control", "bfloat16")
-    if kind == "bfloat16":
-        pods, asked, dtype = cluster["pods"], templates, ml_dtypes.bfloat16
-    elif kind == "namespaces_ignored":
-        pods, asked = _without_namespaces(cluster["pods"]), \
-            _without_namespaces(templates)
-        dtype = np.float32
-    else:
-        raise ValueError(f"unknown control {kind!r}")
+    pods, asked, dtype = gen.load_module(
+        f"benchmark/controls/{kind}.py").apply(cluster["pods"], templates)
+    reference = gen.load_module(cfg["reference"])
     rc = reference.Cluster(cluster["nodes"], pods)
     kept = [(k, compare.from_reference(reference.solve(
         rc, t, int(traffic["max_limit"]), dtype=dtype)))
         for k, t in enumerate(asked)]
-    checks = harness.check(cluster, templates, traffic, kept, 0)
+    checks = harness.check(reference, cluster, templates, traffic, kept, 0)
     checks["control"] = kind
     return checks
 

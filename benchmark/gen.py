@@ -2,26 +2,26 @@
 of questions from a traffic file.  Everything is drawn from `--seed`, so
 the same seed gives the same cluster and the same questions.
 
-Cluster generators (the configuration's "generator" key):
-
-- `proportional`: copied from chip_smoke.py `make_cluster`.  Nodes of
-  random size classes in zones, resident pods placed in proportion to node
-  cores.
-- `scheduler_perf`: kube-scheduler's scheduler_perf layout.  Identical
-  nodes from a node template with a unique hostname label each, and init
-  pods from a pod template, one to a node on distinct seeded nodes.
+A configuration names its cluster generator ("generator"): the file
+`generators/<name>.py`, whose `make(cfg, seed)` returns the cluster.  Each
+generator says in its docstring what it lays out.  `load_module` loads
+that file, and every other file a data file names (a configuration's
+reference, a traffic file's control, a metric's reader), by its path.
 """
 
 from __future__ import annotations
 
 import copy
+import importlib.util
 import json
 import os
+import sys
 from typing import List
 
 import numpy as np
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(BENCH)
 
 
 def load_json(rel: str) -> dict:
@@ -29,75 +29,35 @@ def load_json(rel: str) -> dict:
         return json.load(f)
 
 
-def _proportional(cfg: dict, seed: int) -> dict:
-    rng = np.random.default_rng(seed)
-    n, n_pods, zones = cfg["nodes"], cfg["resident_pods"], cfg["zones"]
-    cores = rng.choice(cfg["node_cores"], size=n)
-    mem_gi = rng.choice(cfg["node_memory_gi"], size=n)
-    names = [cfg["node_name"].format(i=i) for i in range(n)]
-    nodes = [{
-        "metadata": {"name": names[i],
-                     "labels": {cfg["hostname_key"]: names[i],
-                                cfg["zone_key"]: cfg["zone_name"].format(
-                                    z=i % zones)}},
-        "spec": {},
-        "status": {"allocatable": {"cpu": str(int(cores[i])),
-                                   "memory": f"{int(mem_gi[i])}Gi",
-                                   "pods": str(cfg["pods_per_node"])}},
-    } for i in range(n)]
-    host = rng.choice(n, size=n_pods, p=cores / cores.sum())
-    cpu_m = rng.choice(cfg["resident_cpu_m"], size=n_pods,
-                       p=cfg["resident_cpu_p"])
-    mem_mi = rng.choice(cfg["resident_memory_mi"], size=n_pods)
-    app = rng.integers(0, cfg["resident_apps"], size=n_pods)
-    pods = [{
-        "metadata": {"name": f"res-{j:06d}",
-                     "namespace": cfg["resident_namespace"],
-                     "labels": {"app": f"svc-{int(app[j])}"}},
-        "spec": {"nodeName": names[int(host[j])],
-                 "containers": [{"name": "c", "resources": {"requests": {
-                     "cpu": f"{int(cpu_m[j])}m",
-                     "memory": f"{int(mem_mi[j])}Mi"}}}]},
-        "status": {"phase": "Running"},
-    } for j in range(n_pods)]
-    return {"nodes": nodes, "pods": pods}
-
-
-def _scheduler_perf(cfg: dict, seed: int) -> dict:
-    rng = np.random.default_rng(seed)
-    n = cfg["nodes"]
-    node_tpl = load_json(cfg["node_template"])["object"]
-    nodes = []
-    for i in range(n):
-        node = copy.deepcopy(node_tpl)
-        name = cfg["node_name"].format(i=i)
-        meta = node["metadata"]
-        meta.pop("generateName", None)
-        meta["name"] = name
-        meta.setdefault("labels", {})[cfg["unique_label"]] = name
-        nodes.append(node)
-    pod_tpl = load_json(cfg["init_pod_template"])["object"]
-    hosts = rng.choice(n, size=cfg["init_pods"], replace=False)
-    pods = []
-    for j, h in enumerate(hosts):
-        pod = copy.deepcopy(pod_tpl)
-        meta = pod["metadata"]
-        meta.pop("generateName", None)
-        meta["name"] = cfg["init_pod_name"].format(j=j)
-        meta["namespace"] = cfg["init_namespace"]
-        pod["spec"]["nodeName"] = nodes[int(h)]["metadata"]["name"]
-        pod["status"] = {"phase": "Running"}
-        pods.append(pod)
-    return {"nodes": nodes, "pods": pods}
-
-
-GENERATORS = {"proportional": _proportional,
-              "scheduler_perf": _scheduler_perf}
+def load_module(rel: str):
+    """The Python file at `rel` (a path under benchmark/, relative to the
+    checkout), loaded once per process.  Its module name is its path under
+    benchmark/ with dots for slashes, the name a plain `import` of the same
+    file gives, so a file that imports another by name shares the module
+    loaded here."""
+    bench = os.path.realpath(BENCH)
+    path = os.path.realpath(os.path.join(ROOT, rel))
+    if os.path.commonpath([path, bench]) != bench or \
+            not path.endswith(".py"):
+        raise ValueError(f"{rel!r} is not a Python file under benchmark/")
+    name = os.path.relpath(path, bench)[:-3].replace(os.sep, ".")
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    return sys.modules[name]
 
 
 def make_cluster(cfg: dict, seed: int) -> dict:
-    """{"nodes": [...], "pods": [...]} as Kubernetes objects."""
-    return GENERATORS[cfg["generator"]](cfg, seed)
+    """{"nodes": [...], "pods": [...]} as Kubernetes objects, from the
+    configuration's generator."""
+    return load_module(
+        f"benchmark/generators/{cfg['generator']}.py").make(cfg, seed)
 
 
 def templates(traffic: dict) -> List[dict]:

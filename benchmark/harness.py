@@ -20,14 +20,13 @@ Set-up builds the cluster and the snapshot from the seed and asks every
 question of the catalogue once, so that every program is compiled (or
 loaded from the compile cache) and every runtime cross-check has run
 before the window.  The window closes at the first answer after
-`--seconds`.  Then every answer of the window is compared with the plain
-reference (reference.py) by compare.py.
+`--seconds`.  Then every answer of the window is compared by compare.py
+with the plain reference that the configuration names ("reference").
 """
 
 from __future__ import annotations
 
 import gc
-import importlib.util
 import json
 import os
 import shutil
@@ -188,12 +187,7 @@ def quantile(values: List[float], q: int) -> float:
 
 def load_reader(name: str):
     base = name.split(".")[0]
-    path = os.path.join(HERE, "metrics", base + ".py")
-    spec = importlib.util.spec_from_file_location("bench_metric_" + base,
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return gen.load_module(f"benchmark/metrics/{base}.py").read
 
 
 def run(workload: str, seed: int, seconds: float, trace: bool,
@@ -333,19 +327,19 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     # correctness: every answer of the window against the reference
     del asker, snapshot, answers, review
     gc.collect()
-    checks = check(cluster, raw, traffic, kept, failed)
+    checks = check(gen.load_module(cfg["reference"]), cluster, raw, traffic,
+                   kept, failed)
     result["correct"] = checks.pop("_correct")
     result["checks"] = checks
     return result
 
 
-def check(cluster: dict, templates: List[dict], traffic: dict,
+def check(reference, cluster: dict, templates: List[dict], traffic: dict,
           kept: list, failed: int) -> dict:
-    """Compare every kept answer with the reference's answer to its
-    template (the raw template, as the traffic file gives it); returns
+    """Compare every kept answer with the `reference` module's answer to
+    its template (the raw template, as the traffic file gives it); returns
     each number the traffic file limits, with its limit, and `_correct`.
     An answer that raised never came, so any failed one is not correct."""
-    import reference
     t0 = time.perf_counter()
     ref_cluster = reference.Cluster(cluster["nodes"], cluster["pods"])
     refs = {k: compare.from_reference(reference.solve(

@@ -15,6 +15,15 @@ configurations' stated precision, and a lower one (bfloat16) for the
 control.  Counts of pods are held in `dtype` as well, as the engine holds
 them.  A feature this reference does not model raises `Unsupported`, so a
 new cell can never be checked against a silently wrong reference.
+
+A configuration names the reference it is checked against ("reference",
+a path under benchmark/); the harness and the control load that file by
+its path.  Every reference keeps this module's contract: `Cluster(nodes,
+pods)` from the generated Kubernetes objects, and `solve(cluster, pod,
+max_limit, dtype=...)` returning an answer with `per_node()` (node name
+to replicas) and `reasons` (refusal reason to nodes, where the answer
+ended on a pod no node takes).  Another reference may `import reference`
+and extend it; like this one, it imports nothing of the program.
 """
 
 from __future__ import annotations

@@ -7,7 +7,7 @@ import time
 
 import pytest
 
-from helpers import TINY, cells, last_json
+from helpers import cells, last_json, question, rehearsal
 
 import control
 import harness
@@ -15,10 +15,38 @@ import harness
 
 @pytest.mark.parametrize("workload,config", cells())
 def test_control_is_not_correct(workload, config):
-    checks = control.readings(workload, 5, TINY[config])
+    checks = control.readings(workload, 5, rehearsal(config))
     checks.pop("control")
     assert checks.pop("_correct") is False
     assert any(v["value"] > v["limit"] for v in checks.values())
+
+
+# The control's numbers at rehearsal size on seed 5, as they read before
+# each configuration's generator, reference and control became files of
+# their own: the same cluster, reference and arithmetic give the same
+# numbers.
+READINGS = {
+    "k8s5k-spread-full": ("bfloat16", {"node_gap": 0.10132450331125828,
+                                       "reason_gap": 0.6666666666666666,
+                                       "failed_answers": 0}),
+    "sched5k-antiaffinity-full": ("namespaces_ignored",
+                                  {"node_gap": 0.1, "failed_answers": 0}),
+    "sched5k-basic-1k": ("bfloat16", {"node_gap": 0.58,
+                                      "failed_answers": 0}),
+    "k8s5k-sweep8-full": ("bfloat16", {"node_gap": 0.10132450331125828,
+                                       "reason_gap": 0.6666666666666666,
+                                       "failed_answers": 0}),
+}
+
+
+@pytest.mark.parametrize("workload", sorted(READINGS))
+def test_control_readings_pinned(workload):
+    config = dict(cells())[workload]
+    checks = control.readings(workload, 5, rehearsal(config))
+    kind, want = READINGS[workload]
+    assert checks.pop("control") == kind
+    assert checks.pop("_correct") is False
+    assert {k: v["value"] for k, v in checks.items()} == want
 
 
 def _placements_unchanged(result):
@@ -55,7 +83,7 @@ FAULTS = {"state_unchanged": _placements_unchanged,
 def _plant(monkeypatch, workload, fault):
     from cluster_capacity_tpu.parallel import sweep as sweep_mod
     from cluster_capacity_tpu.runtime import degrade
-    if "sweep" in workload:
+    if question(workload) == "sweep":
         real = sweep_mod.sweep
 
         def broken(*a, **kw):
@@ -73,7 +101,7 @@ def _plant(monkeypatch, workload, fault):
 
 
 CASES = [(w, c, f) for w, c in cells() for f in FAULTS
-         if f != "half_batch_left_out" or "sweep" in w]
+         if f != "half_batch_left_out" or question(w) == "sweep"]
 
 
 @pytest.mark.parametrize("workload,config,fault", CASES)
@@ -81,7 +109,8 @@ def test_fault_is_not_correct(workload, config, fault, monkeypatch, capsys):
     _plant(monkeypatch, workload, fault)
     rc = harness.main(["--workload", workload, "--seed", "4000000003",
                        "--seconds", "1", "--trace", "0"],
-                      rehearsal=TINY[config], t_start=time.perf_counter())
+                      rehearsal=rehearsal(config),
+                      t_start=time.perf_counter())
     assert rc == 0
     line = last_json(capsys.readouterr().out)
     assert line["correct"] is False
